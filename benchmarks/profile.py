@@ -11,10 +11,9 @@ Modes:
 * default        — analytic profiles from the catalog roofline constants
   (provenance ``analytic``): the calibration scaffold CI smokes, and the
   fallback wherever no accelerator is attached;
-* ``--engine``   — measure a real :class:`InferenceEngine` on THIS host
-  (provenance ``measured-tpu`` / ``measured-cpu``): full config on TPU,
-  the reduced config elsewhere, tiny grids so the CPU path stays
-  CI-sized;
+* ``--engine``   — measure a real :class:`InferenceEngine` on this host's
+  TPU (provenance ``measured-tpu``) at the model's full config; it fails
+  where JAX finds no TPU;
 * ``--kernel-bench`` — the paged-attention tiling microbench
   (before/after ``pages_per_tile``), reported as CSV rows.
 
@@ -31,6 +30,7 @@ from benchmarks.common import emit
 from repro.bench.profile import (analytic_profile, measure_engine_profile,
                                  paged_kernel_microbench)
 from repro.cluster import hardware as hwlib
+from repro.launch.compile_cache import setup_compile_cache
 
 
 def _row(name: str, prof) -> None:
@@ -52,12 +52,12 @@ def main(argv=None) -> None:
     ap.add_argument("--out", default="results/profiles",
                     help="artifact directory (created if missing)")
     ap.add_argument("--engine", action="store_true",
-                    help="measure a real InferenceEngine on this host "
-                         "(reduced config off-TPU) instead of deriving "
-                         "analytic rows")
+                    help="measure a real InferenceEngine on this host's "
+                         "TPU instead of deriving analytic rows")
     ap.add_argument("--kernel-bench", action="store_true",
                     help="also run the paged-attention tiling microbench")
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     outdir = pathlib.Path(args.out)
     outdir.mkdir(parents=True, exist_ok=True)
@@ -66,11 +66,11 @@ def main(argv=None) -> None:
         hw = hwlib.catalog(name)
         if args.engine:
             import jax
-            from repro.configs import get_config, reduce_config
-            cfg = get_config(args.model)
+            from repro.configs import get_config
             if jax.default_backend() != "tpu":
-                cfg = reduce_config(cfg)
-            prof = measure_engine_profile(cfg, hw)
+                raise SystemExit("--engine measures a TPU; JAX found "
+                                 f"{jax.default_backend()!r}")
+            prof = measure_engine_profile(get_config(args.model), hw)
         else:
             prof = analytic_profile(hw, fp)
         path = outdir / f"{name}__{args.model}.json"

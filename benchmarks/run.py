@@ -114,6 +114,8 @@ def main() -> None:
                     help="override the workload seed of every "
                          "seed-accepting scenario suite")
     args = ap.parse_args()
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
 
     suites = _suites(args.fast)
     only = [s for s in args.only.split(",") if s]

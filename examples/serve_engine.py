@@ -16,9 +16,10 @@ def main():
     cfg = reduce_config(get_config("llama3.1-8b"))
     print(f"serving {cfg.name}: {cfg.num_layers}L d={cfg.d_model} "
           f"vocab={cfg.vocab_size}")
-    engines = [InferenceEngine(cfg, max_batch=4, max_len=96, seed=i,
+    # replicas of one model: one seed for all
+    engines = [InferenceEngine(cfg, max_batch=4, max_len=96,
                                prefill_chunk=16)
-               for i in range(2)]
+               for _ in range(2)]
     est = EMAEstimator()
     rng = np.random.default_rng(0)
 

@@ -4,8 +4,10 @@ This is the functional engine the proxy routes to — it runs real prefill
 and decode steps (the same ``repro.models`` code the dry-run lowers for
 TPU), manages request lifecycles, reports the black-box timing events the
 EMA estimator consumes, and supports token-ID checkpointing of in-flight
-requests (the migration/fault-tolerance path).  On CPU it serves reduced
-configs; on TPU the same class serves full configs on a mesh.
+requests (the migration/fault-tolerance path).  Each engine lives on one
+device, which holds its params and cache (one engine per chip): on CPU it
+serves reduced configs, on a TPU chip published widths with the depth
+cut to what one chip holds (see ``repro.launch.serve``).
 
 Chunked prefill (``prefill_chunk=N``): instead of admitting a prompt as
 one monolithic prefill that stalls every co-batched decode, the queue
@@ -19,6 +21,7 @@ one-shot path.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from collections import deque
 from typing import Deque, List, Optional
@@ -26,12 +29,13 @@ from typing import Deque, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
+from jax.sharding import SingleDeviceSharding
 
 from repro.configs.base import ModelConfig
 from repro.distributed.context import NULL_CTX, ShardCtx
 from repro.models import (decode_step, init_cache, init_params, prefill,
                           prefill_chunk, ring_convert_cache)
-from repro.models.model import logits_fn
 
 
 @dataclasses.dataclass
@@ -42,6 +46,10 @@ class EngineRequest:
     max_new_tokens: int = 64
     eos_id: Optional[int] = None
     done: bool = False
+    # keep_logits: the engine appends the [V] logits row (numpy) of every
+    # token it generates for this request to ``logits``
+    keep_logits: bool = False
+    logits: List[np.ndarray] = dataclasses.field(default_factory=list)
 
     @property
     def generated(self) -> List[int]:
@@ -50,24 +58,42 @@ class EngineRequest:
 
 class InferenceEngine:
     """Static-batch continuous decoding engine (batch slots + shared
-    dense cache; the paged Pallas kernel is the TPU fast path)."""
+    dense cache; the paged Pallas kernel is the TPU fast path).
+
+    ``device`` (default ``jax.devices()[0]``) holds the params and the
+    cache; ``params=None`` initialises them there from ``seed``, so
+    replicas built with one seed serve one model."""
 
     def __init__(self, cfg: ModelConfig, params=None, *, max_batch: int = 8,
                  max_len: int = 256, ctx: ShardCtx = NULL_CTX, seed: int = 0,
                  greedy: bool = True, prefill_chunk: Optional[int] = None,
-                 max_events: int = 4096):
+                 max_events: int = 4096, device=None):
         self.cfg = cfg
         self.ctx = ctx
         self.max_batch = max_batch
         self.max_len = max_len
         self.greedy = greedy
-        self.params = params if params is not None else init_params(
-            cfg, jax.random.PRNGKey(seed), dtype=jnp.float32)
-        self.cache = init_cache(cfg, max_batch, max_len, dtype=jnp.float32)
+        self.device = device if device is not None else jax.devices()[0]
+        on_device = SingleDeviceSharding(self.device)
+        if params is None:
+            params = jax.jit(
+                lambda k: init_params(cfg, k, dtype=jnp.float32),
+                out_shardings=on_device)(jax.random.PRNGKey(seed))
+        self.params = jax.device_put(params, on_device)
+        self.cache = jax.jit(
+            lambda: init_cache(cfg, max_batch, max_len, dtype=jnp.float32),
+            out_shardings=on_device)()
+        self._staging_cache = jax.jit(
+            lambda: init_cache(cfg, 1, max_len, dtype=jnp.float32,
+                               ring=False),
+            out_shardings=on_device)
         self.slots: List[Optional[EngineRequest]] = [None] * max_batch
         self.queue: List[EngineRequest] = []
-        self._decode = jax.jit(
-            lambda p, c, t: decode_step(p, cfg, c, t, ctx=ctx))
+        self._decode = jit_decode(cfg, ctx)
+        # one compile per prompt length
+        self._prefill = jax.jit(functools.partial(
+            greedy_prefill, cfg=cfg, max_len=max_len, ctx=ctx))
+        self._splice = jax.jit(splice_cache, donate_argnums=0)
         # chunked prefill: only full/window mixers are chunk-resumable
         chunkable = all(blk.mixer in ("full", "window")
                         for blk in cfg.layer_list())
@@ -88,6 +114,12 @@ class InferenceEngine:
 
     def submit(self, req: EngineRequest):
         self.queue.append(req)
+
+    @property
+    def pending(self) -> int:
+        """Requests queued, staged or running: the router's load signal."""
+        return (len(self.queue) + (self._staging is not None)
+                + sum(r is not None for r in self.slots))
 
     def drain_events(self) -> List[tuple]:
         """Hand the accumulated (kind, size, dt) timing events to the
@@ -124,25 +156,22 @@ class InferenceEngine:
                 self.events.append(("prefill", req.prompt_len,
                                     time.perf_counter() - t0))
 
-    def _prefill_into_slot(self, slot: int, req: EngineRequest):
-        toks = jnp.asarray(req.tokens, jnp.int32)[None]
-        logits, cache1 = prefill(self.params, self.cfg, toks,
-                                 max_len=self.max_len, ctx=self.ctx)
-        self._splice(slot, cache1, int(cache1["pos"][0]))
-        nxt = int(jnp.argmax(logits[0]))
+    def _to_device(self, x: np.ndarray):
+        return jax.device_put(x, self.device)
+
+    def _emit_first(self, slot: int, req: EngineRequest, logits, nxt: int):
+        if req.keep_logits:
+            req.logits.append(np.asarray(logits[0]))
         req.tokens.append(nxt)
         self.slots[slot] = req
 
-    def _splice(self, slot: int, cache1, pos: int):
-        """Copy a single-request (ring-layout) cache into the batch cache
-        at ``slot``."""
-        def splice(batch_leaf, one_leaf):
-            return batch_leaf.at[:, slot].set(one_leaf[:, 0]) \
-                if batch_leaf.ndim >= 2 else batch_leaf
-        for si in range(len(self.cache["stages"])):
-            self.cache["stages"][si] = jax.tree.map(
-                splice, self.cache["stages"][si], cache1["stages"][si])
-        self.cache["pos"] = self.cache["pos"].at[slot].set(pos)
+    def _prefill_into_slot(self, slot: int, req: EngineRequest):
+        toks = self._to_device(np.asarray(req.tokens, np.int32)[None])
+        logits, nxt, cache1 = self._prefill(self.params, toks)
+        nxt = int(nxt[0])
+        self.cache = self._splice(self.cache, cache1, np.int32(slot),
+                                  np.int32(len(req.tokens)))
+        self._emit_first(slot, req, logits, nxt)
 
     def _advance_staged(self):
         """Begin and/or advance the staged prefill by at most one chunk —
@@ -154,8 +183,7 @@ class InferenceEngine:
                 return
             self._staging = {
                 "slot": free, "req": self.queue.pop(0),
-                "cache": init_cache(self.cfg, 1, self.max_len,
-                                    dtype=jnp.float32, ring=False),
+                "cache": self._staging_cache(),
                 "t0": time.perf_counter(), "done": 0}
         st = self._staging
         req, C = st["req"], self.prefill_chunk
@@ -163,17 +191,18 @@ class InferenceEngine:
         toks = np.zeros((1, C), np.int32)
         toks[0, :n] = req.tokens[st["done"]:st["done"] + n]
         logits, st["cache"] = self._chunk_fn(
-            self.params, st["cache"], jnp.asarray(toks),
-            jnp.asarray([n], jnp.int32))
+            self.params, st["cache"], self._to_device(toks),
+            self._to_device(np.asarray([n], np.int32)))
         st["done"] += n
         if st["done"] < req.prompt_len:
             return
         # prompt complete: ring-convert, splice, emit the first token
         ring = ring_convert_cache(self.cfg, st["cache"], self.max_len,
                                   req.prompt_len)
-        self._splice(st["slot"], ring, req.prompt_len)
-        req.tokens.append(int(jnp.argmax(logits[0])))
-        self.slots[st["slot"]] = req
+        self.cache = self._splice(self.cache, ring, np.int32(st["slot"]),
+                                  np.int32(req.prompt_len))
+        self._emit_first(st["slot"], req, logits,
+                         int(jnp.argmax(logits[0])))
         self.events.append(("prefill", req.prompt_len,
                             time.perf_counter() - st["t0"]))
         self._staging = None
@@ -190,14 +219,20 @@ class InferenceEngine:
         toks = np.zeros((self.max_batch, 1), np.int32)
         for i in active:
             toks[i, 0] = self.slots[i].tokens[-1]
+        # the clock stops once the next tokens are on the host: dispatch
+        # is asynchronous, so stopping at the call would time the enqueue
         t0 = time.perf_counter()
-        logits, self.cache = self._decode(self.params, self.cache,
-                                          jnp.asarray(toks))
+        logits, nxt, self.cache = self._decode(self.params, self.cache,
+                                               self._to_device(toks))
+        nxt = np.asarray(nxt)
         self.events.append(("decode", len(active),
                             time.perf_counter() - t0))
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))
+        kept = (np.asarray(logits)
+                if any(self.slots[i].keep_logits for i in active) else None)
         for i in active:
             req = self.slots[i]
+            if req.keep_logits:
+                req.logits.append(kept[i])
             req.tokens.append(int(nxt[i]))
             full = len(req.tokens) >= min(
                 req.prompt_len + req.max_new_tokens, self.max_len - 1)
@@ -214,6 +249,38 @@ class InferenceEngine:
             if n == 0 and not self.queue and self._staging is None:
                 break
         return self.completed
+
+
+def greedy_decode(params, cache, tokens, *, cfg: ModelConfig,
+                  ctx: ShardCtx = NULL_CTX):
+    """One decode iteration plus its greedy pick: (logits [B, V],
+    next tokens [B], new cache)."""
+    logits, cache = decode_step(params, cfg, cache, tokens, ctx=ctx)
+    return logits, jnp.argmax(logits, axis=-1), cache
+
+
+def jit_decode(cfg: ModelConfig, ctx: ShardCtx = NULL_CTX):
+    """The engine's decode program.  The cache is donated, so the old
+    and the new cache never coexist on the device."""
+    return jax.jit(functools.partial(greedy_decode, cfg=cfg, ctx=ctx),
+                   donate_argnums=1)
+
+
+def greedy_prefill(params, tokens, *, cfg: ModelConfig, max_len: int,
+                   ctx: ShardCtx = NULL_CTX):
+    """Prompt prefill plus its greedy pick: (logits [B, V], next tokens
+    [B], ring-layout cache sized ``max_len``)."""
+    logits, cache = prefill(params, cfg, tokens, max_len=max_len, ctx=ctx)
+    return logits, jnp.argmax(logits, axis=-1), cache
+
+
+def splice_cache(cache, one, slot, pos):
+    """Copy a single-request (ring-layout) cache ``one`` into the batch
+    cache at ``slot`` and set that slot's next position to ``pos``."""
+    stages = jax.tree.map(
+        lambda b, o: lax.dynamic_update_slice_in_dim(b, o, slot, axis=1),
+        cache["stages"], one["stages"])
+    return {"stages": stages, "pos": cache["pos"].at[slot].set(pos)}
 
 
 def prefill_chunk_step(params, cfg, cache, tokens, n_valid, ctx):

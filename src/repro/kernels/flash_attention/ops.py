@@ -1,5 +1,5 @@
-"""Jit wrapper for the flash-attention kernel; interpret mode is chosen
-automatically off-TPU (CPU validates the kernel body in Python)."""
+"""Jit wrapper for the flash-attention kernel: compiled on TPU,
+interpreted on CPU (which validates the kernel body in Python)."""
 from __future__ import annotations
 
 import functools
@@ -7,12 +7,9 @@ from typing import Optional
 
 import jax
 
+from repro.kernels import interpret_mode
 from repro.kernels.flash_attention.flash_attention import \
     flash_attention_pallas
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "softcap",
@@ -23,4 +20,4 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     bq: int = 128, bk: int = 128):
     return flash_attention_pallas(q, k, v, causal=causal, window=window,
                                   softcap=softcap, bq=bq, bk=bk,
-                                  interpret=not _on_tpu())
+                                  interpret=interpret_mode())

@@ -1,10 +1,11 @@
-"""Jit wrapper for paged decode attention (interpret off-TPU)."""
+"""Jit wrapper for paged decode attention (interpreted on CPU)."""
 from __future__ import annotations
 
 import functools
 
 import jax
 
+from repro.kernels import interpret_mode
 from repro.kernels.paged_attention.paged_attention import \
     paged_attention_pallas
 
@@ -18,4 +19,4 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
     return paged_attention_pallas(
         q, k_pages, v_pages, block_tables, context_lens,
         pages_per_tile=pages_per_tile,
-        interpret=jax.default_backend() != "tpu")
+        interpret=interpret_mode())

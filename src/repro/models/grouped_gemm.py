@@ -9,44 +9,20 @@ so we register them explicitly:
     y              = ragged_dot(x, w, gs)            [M,N]
     dx             = ragged_dot'(dy, w, gs)           contract N -> [M,K]
     dw[g]          = x_g^T dy_g  (ragged-contracting) -> [G,K,N]
-
-JAX-version compatibility: ``lax.ragged_dot_general`` and
-``RaggedDotDimensionNumbers`` only exist on newer JAX (>= 0.5.x).  On
-older installs (e.g. 0.4.37, which still ships ``lax.ragged_dot``) the
-backward pass falls back to a dense one-hot einsum formulation of the
-same two grouped GEMMs.  The fallback is O(M*G) extra memory for the
-group-assignment mask — fine at test scale, and the ragged path is
-picked automatically whenever the installed JAX provides it.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.lax import RaggedDotDimensionNumbers, ragged_dot_general
 
-try:  # JAX >= 0.5: ragged-dot autodiff primitives
-    from jax.lax import RaggedDotDimensionNumbers, ragged_dot_general
-    _HAS_RAGGED_GENERAL = True
-except ImportError:  # pragma: no cover - exercised on older JAX
-    RaggedDotDimensionNumbers = None
-    ragged_dot_general = None
-    _HAS_RAGGED_GENERAL = False
-
-if _HAS_RAGGED_GENERAL:
-    _DLHS_DIMS = RaggedDotDimensionNumbers(
-        dot_dimension_numbers=(((1,), (2,)), ((), ())),
-        lhs_ragged_dimensions=[0], rhs_group_dimensions=[0])
-    _DRHS_DIMS = RaggedDotDimensionNumbers(
-        dot_dimension_numbers=(((0,), (0,)), ((), ())),
-        lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
-
-
-def _group_onehot(group_sizes, m: int, dtype) -> jnp.ndarray:
-    """[M, G] one-hot of each row's group, jit-safe via cumsum compare."""
-    bounds = jnp.cumsum(group_sizes)                    # [G]
-    rows = jnp.arange(m)[:, None]                       # [M, 1]
-    starts = bounds - group_sizes
-    return ((rows >= starts[None, :]) & (rows < bounds[None, :])).astype(dtype)
+_DLHS_DIMS = RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((1,), (2,)), ((), ())),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[0])
+_DRHS_DIMS = RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
 
 
 @jax.custom_vjp
@@ -62,18 +38,10 @@ def _fwd(lhs, rhs, group_sizes):
 
 def _bwd(res, dy):
     lhs, rhs, group_sizes = res
-    if _HAS_RAGGED_GENERAL:
-        d_lhs = ragged_dot_general(dy, rhs, group_sizes, _DLHS_DIMS)
-        d_rhs = ragged_dot_general(lhs.astype(jnp.float32),
-                                   dy.astype(jnp.float32), group_sizes,
-                                   _DRHS_DIMS).astype(rhs.dtype)
-    else:
-        onehot = _group_onehot(group_sizes, lhs.shape[0], jnp.float32)
-        d_lhs = jnp.einsum("mn,mg,gkn->mk", dy.astype(jnp.float32), onehot,
-                           rhs.astype(jnp.float32))
-        d_rhs = jnp.einsum("mg,mk,mn->gkn", onehot,
-                           lhs.astype(jnp.float32),
-                           dy.astype(jnp.float32)).astype(rhs.dtype)
+    d_lhs = ragged_dot_general(dy, rhs, group_sizes, _DLHS_DIMS)
+    d_rhs = ragged_dot_general(lhs.astype(jnp.float32),
+                               dy.astype(jnp.float32), group_sizes,
+                               _DRHS_DIMS).astype(rhs.dtype)
     return d_lhs.astype(lhs.dtype), d_rhs, None
 
 

@@ -140,8 +140,9 @@ def _sdpa_chunked(q, k, v, window: Optional[int],
     B, Lq, H, hd = q.shape
     if Lq <= chunk:
         return _sdpa(q, k, v, causal_mask(Lq, Lq, window), softcap)
-    assert Lq % chunk == 0, (Lq, chunk)
-    n = Lq // chunk
+    # a ragged tail pads the queries only: padded rows are dropped below
+    n = -(-Lq // chunk)
+    q = jnp.pad(q, ((0, 0), (0, n * chunk - Lq), (0, 0), (0, 0)))
     qs = q.reshape(B, n, chunk, H, hd).transpose(1, 0, 2, 3, 4)
     offs = jnp.arange(n) * chunk
 
@@ -158,7 +159,7 @@ def _sdpa_chunked(q, k, v, window: Optional[int],
     # instead of stacking [n_chunks, ..., Lk] fp32 probs across the scan
     _, out = lax.scan(jax.checkpoint(body, prevent_cse=False), None,
                       (qs, offs))
-    return out.transpose(1, 0, 2, 3).reshape(B, Lq, H * hd)
+    return out.transpose(1, 0, 2, 3).reshape(B, n * chunk, H * hd)[:, :Lq]
 
 
 def causal_mask(Lq: int, Lk: int, window: Optional[int] = None,
@@ -487,7 +488,6 @@ def moe_forward(p, cfg: ModelConfig, x, ctx: ShardCtx = NULL_CTX):
               and B % ctx.dp_size == 0
               and mo.d_ff_expert % ctx.tp_size == 0)
     if use_sm:
-        from jax.experimental.shard_map import shard_map
         bspec = ctx.data_axes if len(ctx.data_axes) > 1 else ctx.data_axes[0]
         w_specs = {
             "ln": P(None),
@@ -516,12 +516,12 @@ def moe_forward(p, cfg: ModelConfig, x, ctx: ShardCtx = NULL_CTX):
         h = rms_norm(x, p["ln"], cfg.norm_eps)
         h = ctx.constraint(h, bspec, None, None)
         pp = {k: v for k, v in p.items() if k != "ln"}
-        y, aux = shard_map(
+        y, aux = jax.shard_map(
             body, mesh=ctx.mesh,
             in_specs=(P(bspec, None, None),
                       {k: w_specs[k] for k in pp}),
             out_specs=(P(bspec, None, None), P()),
-            check_rep=False,
+            check_vma=False,
         )(h, pp)
         return y, aux
     # plain path (smoke tests, decode, tiny batches)

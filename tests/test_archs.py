@@ -110,3 +110,16 @@ def test_moe_active_params():
     cfg = get_config("mixtral-8x22b")
     active = cfg.param_count(active_only=True)
     assert 35e9 < active < 44e9   # published ~39B active
+
+
+def test_forward_takes_lengths_off_the_attention_chunk():
+    """Sequences longer than the 512-query attention chunk need not be a
+    multiple of it: causal outputs on a prefix match the prefix alone."""
+    cfg = reduce_config(get_config("minicpm-2b"))
+    params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    toks = jax.random.randint(jax.random.PRNGKey(1), (1, 600), 0,
+                              cfg.vocab_size)
+    fwd = jax.jit(lambda p, t: model_forward(p, cfg, t, remat=False)[0])
+    long, short = fwd(params, toks), fwd(params, toks[:, :500])
+    np.testing.assert_allclose(np.asarray(long[:, :500]),
+                               np.asarray(short), rtol=1e-5, atol=1e-5)

@@ -136,3 +136,22 @@ def test_paged_cache_exhaustion_on_extend():
     cache.allocate(1, 16)
     with pytest.raises(MemoryError):
         cache.extend(1, 1)
+
+
+def test_engine_places_state_on_its_device_and_keeps_logits():
+    cfg = reduce_config(get_config("llama3.1-8b"))
+    dev = jax.devices()[-1]
+    eng = InferenceEngine(cfg, max_batch=2, max_len=48, device=dev)
+    leaves = jax.tree.leaves((eng.params, eng.cache))
+    assert all(x.devices() == {dev} for x in leaves)
+    r = EngineRequest(rid=0, tokens=list(range(1, 9)), prompt_len=8,
+                      max_new_tokens=4, keep_logits=True)
+    eng.submit(r)
+    eng.step()                        # prefill, then the first decode
+    old = eng.cache
+    eng.step()
+    # the decode step donates the cache: the old buffers are gone
+    assert all(x.is_deleted() for x in jax.tree.leaves(old))
+    eng.run_until_drained()
+    assert len(r.logits) == len(r.generated) == 4
+    assert [int(np.argmax(row)) for row in r.logits] == r.generated

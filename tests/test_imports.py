@@ -1,17 +1,13 @@
-"""Every repro.* module must import on the installed JAX.
-
-Regression for the seed-breaking ``RaggedDotDimensionNumbers``
-ImportError in grouped_gemm (the symbol only exists on newer JAX), which
-made the whole suite fail collection.  The sweep runs in a subprocess
-because ``repro.launch.dryrun`` sets XLA_FLAGS at import time and must
-not poison jax device config for the rest of this process.
+"""Every repro.* module must import on the installed JAX (an import
+error there fails the whole suite's collection).  The sweep runs in a
+subprocess because ``repro.launch.dryrun`` sets XLA_FLAGS at import time
+and must not poison jax device config for the rest of this process.
 """
 import os
 import subprocess
 import sys
 
 import numpy as np
-import pytest
 
 _SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
@@ -33,20 +29,12 @@ def test_every_repro_module_imports():
     assert int(proc.stdout.strip()) >= 25
 
 
-@pytest.mark.parametrize("force_fallback", [False, True])
-def test_grouped_gemm_grads_match_dense_reference(force_fallback,
-                                                  monkeypatch):
+def test_grouped_gemm_grads_match_dense_reference():
     """The backward pass must agree with a dense per-group reference on
-    both gradients — on the native ragged path (when the installed JAX
-    has it) AND on the version-compat dense fallback, which we force via
-    the module flag so CI on new JAX still covers it."""
+    both gradients."""
     import jax
     import jax.numpy as jnp
-    from repro.models import grouped_gemm as gg
     from repro.models.grouped_gemm import grouped_gemm
-
-    if force_fallback:
-        monkeypatch.setattr(gg, "_HAS_RAGGED_GENERAL", False)
 
     rng = np.random.default_rng(0)
     gs = np.array([3, 0, 5, 4], np.int32)

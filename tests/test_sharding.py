@@ -93,3 +93,26 @@ def test_embed_never_fsdp():
     specs = shd.make_param_specs(shapes, FAKE_MESH, fsdp=True)
     assert "data" not in str(specs["embed"])
     assert "data" not in str(specs["lm_head"])
+
+
+def test_moe_shard_map_dispatch_matches_plain_path():
+    """The MoE dispatch under ``jax.shard_map`` (taken whenever a mesh
+    is active) agrees with the plain path; a 1x1 mesh runs it on one
+    device."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from repro.configs import reduce_config
+    from repro.distributed.context import ShardCtx
+    from repro.models.layers import init_moe, moe_forward
+
+    cfg = reduce_config(get_config("mixtral-8x22b"))
+    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
+    p = init_moe(jax.random.PRNGKey(0), cfg, jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, cfg.d_model))
+    y0, aux0 = moe_forward(p, cfg, x)
+    y1, aux1 = jax.jit(
+        lambda p, x: moe_forward(p, cfg, x, ShardCtx(mesh=mesh)))(p, x)
+    np.testing.assert_allclose(np.asarray(y1), np.asarray(y0),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(float(aux1), float(aux0), rtol=1e-5)
