@@ -33,7 +33,8 @@ from repro.configs import get_config  # noqa: E402
 from repro.configs.base import ModelConfig  # noqa: E402
 from repro.engine.engine import EngineRequest  # noqa: E402
 from repro.launch import serve as launcher  # noqa: E402
-from repro.launch.compile_cache import setup_compile_cache  # noqa: E402
+from repro.launch.compile_cache import (CompileLog,  # noqa: E402
+                                        setup_compile_cache)
 from repro.models import model_forward  # noqa: E402
 from repro.models.model import logits_fn  # noqa: E402
 
@@ -141,29 +142,6 @@ def replicas_phase(size: Size, devices, seed: int) -> Dict:
     return {"result": many, "errors": errs, "agree": agree}
 
 
-class _CompileLog:
-    """Programs compiled (or loaded from the persistent cache) and the
-    seconds spent on it, from JAX's own monitoring events."""
-
-    def __init__(self):
-        self.n, self.seconds, self.cache_hits = 0, 0.0, 0
-        jax.monitoring.register_event_duration_secs_listener(self._dur)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _dur(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.n += 1
-            self.seconds += duration
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def line(self) -> str:
-        return (f"compiles: {self.n} programs in {self.seconds:.3f} s "
-                f"({self.cache_hits} loaded from the persistent cache)")
-
-
 def _peak(device) -> str:
     stats = device.memory_stats() or {}
     return f"{stats.get('peak_bytes_in_use', 'not reported')}"
@@ -182,7 +160,7 @@ def main(argv=None) -> None:
         sys.exit(f"chip_smoke: no TPU found (JAX backend is "
                  f"{devices[0].platform!r}); this smoke runs only on a TPU")
     cache_dir = setup_compile_cache()
-    log = _CompileLog()
+    log = CompileLog()
     print(f"device: {devices[0].device_kind} x {len(devices)} "
           f"({devices[0].platform})")
     print(f"compile cache: {cache_dir}")

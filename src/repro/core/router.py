@@ -18,7 +18,6 @@ simulator executes.
 """
 from __future__ import annotations
 
-import time
 from collections import deque
 from typing import List
 
@@ -42,7 +41,6 @@ class Router(cplib.Policy):
     def __init__(self, seed: int = 0):
         super().__init__()
         self.rng = np.random.default_rng(seed)
-        self.decision_times: List[float] = []
 
     @property
     def sim(self):
@@ -92,10 +90,7 @@ class Router(cplib.Policy):
     # -- interface ----------------------------------------------------------
 
     def route(self, sr: SimRequest, t: float) -> int:
-        t0 = time.perf_counter()
-        gid = self._route(sr, t)
-        self.decision_times.append(time.perf_counter() - t0)
-        return gid
+        return self._route(sr, t)
 
     def _route(self, sr: SimRequest, t: float) -> int:
         raise NotImplementedError

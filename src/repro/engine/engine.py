@@ -17,6 +17,21 @@ AccelGen-style iteration shaping, which is what keeps decode TPOT stable
 under long-prompt arrivals.  Only full/window-attention configs qualify
 (mamba/MLA states are not chunk-resumable); others silently keep the
 one-shot path.
+
+Spans: the engine marks what the host does in a step with
+``jax.profiler.TraceAnnotation`` spans, each carrying ``dev`` (the
+engine's device id), so a profiler trace says what the host was doing
+in every stretch the device sat idle.  Per admitted request,
+``engine.admit`` (``rid``, ``prompt_len``, ``queued_ms``) holds
+``engine.prefill`` (dispatch), ``engine.sync`` (the host waiting for the
+first token) and ``engine.splice`` (dispatch); a decode runs
+``engine.inputs`` (token array and ``device_put``), ``engine.decode``
+(dispatch), ``engine.sync`` (waiting for the next tokens) and
+``engine.commit`` (tokens appended, completion, kept logits).  Every
+place where the host blocks on the device is an ``engine.sync`` span.
+The programs are jitted module-level functions, so they carry their names
+(``jit_engine_decode``, ``jit_engine_prefill``,
+``jit_engine_prefill_chunk``) in the trace.
 """
 from __future__ import annotations
 
@@ -30,6 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.profiler import TraceAnnotation
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs.base import ModelConfig
@@ -50,6 +66,11 @@ class EngineRequest:
     # token it generates for this request to ``logits``
     keep_logits: bool = False
     logits: List[np.ndarray] = dataclasses.field(default_factory=list)
+    # host times (time.perf_counter) the engine stamps: submitted, its
+    # admission began, its first token reached the host
+    t_submit: Optional[float] = None
+    t_admit: Optional[float] = None
+    t_first: Optional[float] = None
 
     @property
     def generated(self) -> List[int]:
@@ -74,6 +95,7 @@ class InferenceEngine:
         self.max_len = max_len
         self.greedy = greedy
         self.device = device if device is not None else jax.devices()[0]
+        self._dev = self.device.id          # the spans' ``dev`` argument
         on_device = SingleDeviceSharding(self.device)
         if params is None:
             params = jax.jit(
@@ -89,18 +111,17 @@ class InferenceEngine:
             out_shardings=on_device)
         self.slots: List[Optional[EngineRequest]] = [None] * max_batch
         self.queue: List[EngineRequest] = []
-        self._decode = jit_decode(cfg, ctx)
+        self._decode = functools.partial(DECODE, cfg=cfg, ctx=ctx)
         # one compile per prompt length
-        self._prefill = jax.jit(functools.partial(
-            greedy_prefill, cfg=cfg, max_len=max_len, ctx=ctx))
+        self._prefill = functools.partial(PREFILL, cfg=cfg, max_len=max_len,
+                                          ctx=ctx)
         self._splice = jax.jit(splice_cache, donate_argnums=0)
         # chunked prefill: only full/window mixers are chunk-resumable
         chunkable = all(blk.mixer in ("full", "window")
                         for blk in cfg.layer_list())
         self.prefill_chunk = (prefill_chunk
                               if (prefill_chunk and chunkable) else None)
-        self._chunk_fn = jax.jit(
-            lambda p, c, t, n: prefill_chunk_step(p, cfg, c, t, n, ctx))
+        self._chunk_fn = functools.partial(PREFILL_CHUNK, cfg=cfg, ctx=ctx)
         # one request staged at a time: (slot, req, linear cache, t0,
         # tokens consumed, last-chunk logits)
         self._staging: Optional[dict] = None
@@ -113,6 +134,7 @@ class InferenceEngine:
     # -- request lifecycle -----------------------------------------------------
 
     def submit(self, req: EngineRequest):
+        req.t_submit = time.perf_counter()
         self.queue.append(req)
 
     @property
@@ -151,26 +173,39 @@ class InferenceEngine:
         for i in range(self.max_batch):
             if self.slots[i] is None and self.queue:
                 req = self.queue.pop(0)
-                t0 = time.perf_counter()
-                self._prefill_into_slot(i, req)
+                t0 = req.t_admit = time.perf_counter()
+                with self._admit_span(req):
+                    self._prefill_into_slot(i, req)
                 self.events.append(("prefill", req.prompt_len,
                                     time.perf_counter() - t0))
+
+    def _admit_span(self, req: EngineRequest):
+        return TraceAnnotation(
+            "engine.admit", dev=self._dev, rid=req.rid,
+            prompt_len=req.prompt_len,
+            queued_ms=1e3 * (req.t_admit - req.t_submit))
 
     def _to_device(self, x: np.ndarray):
         return jax.device_put(x, self.device)
 
     def _emit_first(self, slot: int, req: EngineRequest, logits, nxt: int):
         if req.keep_logits:
-            req.logits.append(np.asarray(logits[0]))
+            # sliced on the host: a slice on the device would queue
+            # behind the splice and wait for it
+            req.logits.append(np.asarray(logits)[0])
         req.tokens.append(nxt)
         self.slots[slot] = req
 
     def _prefill_into_slot(self, slot: int, req: EngineRequest):
-        toks = self._to_device(np.asarray(req.tokens, np.int32)[None])
-        logits, nxt, cache1 = self._prefill(self.params, toks)
-        nxt = int(nxt[0])
-        self.cache = self._splice(self.cache, cache1, np.int32(slot),
-                                  np.int32(len(req.tokens)))
+        with TraceAnnotation("engine.prefill", dev=self._dev):
+            toks = self._to_device(np.asarray(req.tokens, np.int32)[None])
+            logits, nxt, cache1 = self._prefill(self.params, toks)
+        with TraceAnnotation("engine.sync", dev=self._dev):
+            nxt = int(nxt[0])
+        req.t_first = time.perf_counter()
+        with TraceAnnotation("engine.splice", dev=self._dev):
+            self.cache = self._splice(self.cache, cache1, np.int32(slot),
+                                      np.int32(len(req.tokens)))
         self._emit_first(slot, req, logits, nxt)
 
     def _advance_staged(self):
@@ -181,28 +216,36 @@ class InferenceEngine:
                         None)
             if free is None or not self.queue:
                 return
+            req = self.queue.pop(0)
+            req.t_admit = time.perf_counter()
             self._staging = {
-                "slot": free, "req": self.queue.pop(0),
-                "cache": self._staging_cache(),
-                "t0": time.perf_counter(), "done": 0}
-        st = self._staging
+                "slot": free, "req": req, "cache": self._staging_cache(),
+                "t0": req.t_admit, "done": 0}
+        with self._admit_span(self._staging["req"]):
+            self._advance_chunk(self._staging)
+
+    def _advance_chunk(self, st: dict):
         req, C = st["req"], self.prefill_chunk
         n = min(C, req.prompt_len - st["done"])
-        toks = np.zeros((1, C), np.int32)
-        toks[0, :n] = req.tokens[st["done"]:st["done"] + n]
-        logits, st["cache"] = self._chunk_fn(
-            self.params, st["cache"], self._to_device(toks),
-            self._to_device(np.asarray([n], np.int32)))
+        with TraceAnnotation("engine.prefill", dev=self._dev):
+            toks = np.zeros((1, C), np.int32)
+            toks[0, :n] = req.tokens[st["done"]:st["done"] + n]
+            logits, st["cache"] = self._chunk_fn(
+                self.params, st["cache"], self._to_device(toks),
+                self._to_device(np.asarray([n], np.int32)))
         st["done"] += n
         if st["done"] < req.prompt_len:
             return
         # prompt complete: ring-convert, splice, emit the first token
-        ring = ring_convert_cache(self.cfg, st["cache"], self.max_len,
-                                  req.prompt_len)
-        self.cache = self._splice(self.cache, ring, np.int32(st["slot"]),
-                                  np.int32(req.prompt_len))
-        self._emit_first(st["slot"], req, logits,
-                         int(jnp.argmax(logits[0])))
+        with TraceAnnotation("engine.splice", dev=self._dev):
+            ring = ring_convert_cache(self.cfg, st["cache"], self.max_len,
+                                      req.prompt_len)
+            self.cache = self._splice(self.cache, ring, np.int32(st["slot"]),
+                                      np.int32(req.prompt_len))
+        with TraceAnnotation("engine.sync", dev=self._dev):
+            nxt = int(jnp.argmax(logits[0]))
+        req.t_first = time.perf_counter()
+        self._emit_first(st["slot"], req, logits, nxt)
         self.events.append(("prefill", req.prompt_len,
                             time.perf_counter() - st["t0"]))
         self._staging = None
@@ -216,17 +259,29 @@ class InferenceEngine:
         active = [i for i, r in enumerate(self.slots) if r is not None]
         if not active:
             return 0
-        toks = np.zeros((self.max_batch, 1), np.int32)
-        for i in active:
-            toks[i, 0] = self.slots[i].tokens[-1]
-        # the clock stops once the next tokens are on the host: dispatch
-        # is asynchronous, so stopping at the call would time the enqueue
-        t0 = time.perf_counter()
-        logits, nxt, self.cache = self._decode(self.params, self.cache,
-                                               self._to_device(toks))
-        nxt = np.asarray(nxt)
+        with TraceAnnotation("engine.inputs", dev=self._dev):
+            toks = np.zeros((self.max_batch, 1), np.int32)
+            for i in active:
+                toks[i, 0] = self.slots[i].tokens[-1]
+            # the clock stops once the next tokens are on the host:
+            # dispatch is asynchronous, so stopping at the call would
+            # time the enqueue
+            t0 = time.perf_counter()
+            toks = self._to_device(toks)
+        with TraceAnnotation("engine.decode", dev=self._dev):
+            logits, nxt, self.cache = self._decode(self.params, self.cache,
+                                                   toks)
+        with TraceAnnotation("engine.sync", dev=self._dev):
+            nxt = np.asarray(nxt)
         self.events.append(("decode", len(active),
                             time.perf_counter() - t0))
+        with TraceAnnotation("engine.commit", dev=self._dev):
+            self._commit(active, logits, nxt)
+        return len(active)
+
+    def _commit(self, active: List[int], logits, nxt: np.ndarray):
+        """Append each active request's next token, retire the finished
+        ones and copy the kept logits rows."""
         kept = (np.asarray(logits)
                 if any(self.slots[i].keep_logits for i in active) else None)
         for i in active:
@@ -241,7 +296,6 @@ class InferenceEngine:
                 req.done = True
                 self.completed.append(req)
                 self.slots[i] = None
-        return len(active)
 
     def run_until_drained(self, max_iters: int = 10000):
         for _ in range(max_iters):
@@ -251,7 +305,7 @@ class InferenceEngine:
         return self.completed
 
 
-def greedy_decode(params, cache, tokens, *, cfg: ModelConfig,
+def engine_decode(params, cache, tokens, *, cfg: ModelConfig,
                   ctx: ShardCtx = NULL_CTX):
     """One decode iteration plus its greedy pick: (logits [B, V],
     next tokens [B], new cache)."""
@@ -259,19 +313,16 @@ def greedy_decode(params, cache, tokens, *, cfg: ModelConfig,
     return logits, jnp.argmax(logits, axis=-1), cache
 
 
-def jit_decode(cfg: ModelConfig, ctx: ShardCtx = NULL_CTX):
-    """The engine's decode program.  The cache is donated, so the old
-    and the new cache never coexist on the device."""
-    return jax.jit(functools.partial(greedy_decode, cfg=cfg, ctx=ctx),
-                   donate_argnums=1)
-
-
-def greedy_prefill(params, tokens, *, cfg: ModelConfig, max_len: int,
+def engine_prefill(params, tokens, *, cfg: ModelConfig, max_len: int,
                    ctx: ShardCtx = NULL_CTX):
     """Prompt prefill plus its greedy pick: (logits [B, V], next tokens
     [B], ring-layout cache sized ``max_len``)."""
     logits, cache = prefill(params, cfg, tokens, max_len=max_len, ctx=ctx)
     return logits, jnp.argmax(logits, axis=-1), cache
+
+
+# the decode's earlier name, which the benchmark's fault tests import
+greedy_decode = engine_decode
 
 
 def splice_cache(cache, one, slot, pos):
@@ -283,8 +334,19 @@ def splice_cache(cache, one, slot, pos):
     return {"stages": stages, "pos": cache["pos"].at[slot].set(pos)}
 
 
-def prefill_chunk_step(params, cfg, cache, tokens, n_valid, ctx):
-    """Module-level jit target for one staged chunk (keeps the jitted
-    closure picklable and the engine body readable)."""
+def engine_prefill_chunk(params, cache, tokens, n_valid, *,
+                         cfg: ModelConfig, ctx: ShardCtx = NULL_CTX):
+    """One staged chunk of a chunked prefill."""
     return prefill_chunk(params, cfg, cache, tokens, n_valid=n_valid,
                          ctx=ctx)
+
+
+# The engine's programs: module-level functions jitted with the model
+# configuration static, so that the trace names them after the function.
+# The decode donates the cache, so the old and the new cache never
+# coexist on the device.
+DECODE = jax.jit(engine_decode, static_argnames=("cfg", "ctx"),
+                 donate_argnums=1)
+PREFILL = jax.jit(engine_prefill, static_argnames=("cfg", "max_len", "ctx"))
+PREFILL_CHUNK = jax.jit(engine_prefill_chunk, static_argnames=("cfg", "ctx"))
+

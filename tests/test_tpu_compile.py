@@ -20,7 +20,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.configs.base import uniform_stage
-from repro.engine.engine import greedy_prefill, jit_decode
+from repro.distributed.context import NULL_CTX
+from repro.engine.engine import DECODE, engine_prefill
 from repro.kernels.flash_attention.flash_attention import \
     flash_attention_pallas
 from repro.kernels.paged_attention.paged_attention import \
@@ -134,13 +135,13 @@ def test_minicpm_decode_compiles_at_full_width(one_chip, minicpm_2_layers):
     cfg = minicpm_2_layers
     params, cache = _model_shapes(cfg, one_chip, CHIP_BATCH, CHIP_MAX_LEN)
     toks = _spec(one_chip, (CHIP_BATCH, 1), jnp.int32)
-    jit_decode(cfg).lower(params, cache, toks).compile()
+    DECODE.lower(params, cache, toks, cfg=cfg, ctx=NULL_CTX).compile()
 
 
 def test_minicpm_prefill_compiles_at_full_width(one_chip, minicpm_2_layers):
     cfg = minicpm_2_layers
     params, _ = _model_shapes(cfg, one_chip, 1, CHIP_MAX_LEN)
-    fn = jax.jit(lambda p, t: greedy_prefill(p, t, cfg=cfg,
+    fn = jax.jit(lambda p, t: engine_prefill(p, t, cfg=cfg,
                                              max_len=CHIP_MAX_LEN))
     fn.lower(params, _spec(one_chip, (1, 512), jnp.int32)).compile()
 
@@ -151,6 +152,7 @@ def test_smoke_decode_fits_one_chip(one_chip):
     cfg = chip_config("minicpm-2b")
     params, cache = _model_shapes(cfg, one_chip, CHIP_BATCH, CHIP_MAX_LEN)
     toks = _spec(one_chip, (CHIP_BATCH, 1), jnp.int32)
-    compiled = jit_decode(cfg).lower(params, cache, toks).compile()
+    compiled = DECODE.lower(params, cache, toks, cfg=cfg,
+                            ctx=NULL_CTX).compile()
     assert compiled.memory_analysis().alias_size_in_bytes > 0   # donated
     assert _total_bytes(compiled) < V5E_USABLE_BYTES
