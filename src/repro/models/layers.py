@@ -191,21 +191,53 @@ def attn_decode(p, cfg: ModelConfig, x, cache_k, cache_v, pos,
     For window layers the cache is a ring buffer of size min(S, window)
     written at ``pos % S``; RoPE is applied pre-cache so ring order is
     irrelevant to scores.
+
+    Attention reads the cache with the new token selected in at its slot
+    (``select_token``, which fuses into the score and value reads).
+    Returns (y, (k_new, v_new)), the token's rows [B,1,KV,hd], which
+    ``model.decode_step`` stores in one of two ways:
+
+    - under a mesh (``ctx.enabled``: the sharded dry-run, whose cache may
+      be sharded on S), ``select_token`` over the whole layer slice, which
+      is written back: elementwise along S, so GSPMD keeps it local;
+    - with no mesh (every served engine), ``write_token_rows`` once per
+      stage after all its layers have read the cache: B row writes in
+      place, instead of a rewrite of the whole cache every layer.
     """
-    B, S = cache_k.shape[0], cache_k.shape[1]
+    S = cache_k.shape[1]
     h = rms_norm(x, p["ln"], cfg.norm_eps)
     q, k_new, v_new = _qkv(p, cfg, h, pos[:, None], ctx, decode=True)
-    slot = pos % S
-    # masked-select update instead of scatter: elementwise along the cache's
-    # (possibly sequence-sharded) S dim, so GSPMD never falls back to the
-    # "involuntary full rematerialization" replication path
-    hit = (jnp.arange(S)[None, :] == slot[:, None])[..., None, None]
-    cache_k = jnp.where(hit, k_new[:, 0][:, None], cache_k)
-    cache_v = jnp.where(hit, v_new[:, 0][:, None], cache_v)
     # valid slots: ring full once pos >= S-1, else slots <= pos
     valid = (jnp.arange(S)[None, :] <= pos[:, None]) | (pos[:, None] >= S)
-    y = _sdpa(q, cache_k, cache_v, valid[:, None, :], cfg.logit_softcap)
-    return y @ p["wo"], (cache_k, cache_v)
+    y = _sdpa(q, select_token(cache_k, k_new, pos),
+              select_token(cache_v, v_new, pos), valid[:, None, :],
+              cfg.logit_softcap)
+    return y @ p["wo"], (k_new, v_new)
+
+
+def select_token(cache, new, pos):
+    """``cache`` [B,S,KV,hd] with row b's slot ``pos[b] % S`` replaced by
+    ``new`` [B,1,KV,hd]: a masked select over the whole cache.  It is
+    elementwise along a (possibly sequence-sharded) S, so GSPMD never falls
+    back to its "involuntary full rematerialization" replication path."""
+    S = cache.shape[1]
+    hit = (jnp.arange(S)[None, :] == (pos % S)[:, None])[..., None, None]
+    return jnp.where(hit, new, cache)
+
+
+def write_token_rows(cache, new, pos):
+    """Write ``new`` [..., B,1,KV,hd] into ``cache`` [..., B,S,KV,hd] at
+    row b's slot ``pos[b] % S``, in place: one dynamic_update_slice per
+    row, which writes B tokens and keeps the cache's layout (a scatter
+    made the TPU compiler relay out and copy the whole cache)."""
+    lead = cache.ndim - 4
+    B, S = cache.shape[lead], cache.shape[lead + 1]
+    slot = pos % S
+    for b in range(B):
+        cache = lax.dynamic_update_slice(
+            cache, lax.slice_in_dim(new, b, b + 1, axis=lead),
+            (0,) * lead + (b, slot[b], 0, 0))
+    return cache
 
 
 def attn_chunk(p, cfg: ModelConfig, x, cache_k, cache_v, pos0,

@@ -388,23 +388,32 @@ def decode_step(params, cfg: ModelConfig, cache, tokens,
         # the cache rides the scan CARRY and is updated in place at the
         # layer index — XLA aliases while-loop carries, so decode keeps a
         # single cache buffer instead of stacked xs/ys copies (which cost
-        # +2x cache per k/v at 32k contexts)
+        # +2x cache per k/v at 32k contexts).  Attention layers store
+        # their new token as ``L.attn_decode`` says: under a mesh by a
+        # select into the layer slice inside the scan; with no mesh the
+        # scan only reads the cache and emits the token's rows, which
+        # ``L.write_token_rows`` writes after it.
         def body(carry, inp, stage=stage):
             xx, cache_full = carry
             i, layer_p = inp
             layer_c = jax.tree.map(
                 lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
                 cache_full)
-            new_c = {}
+            new_c, rows = {}, {}
             for pi, blk in enumerate(stage.pattern):
                 p_ = layer_p[f"blk{pi}"]
                 if blk.mixer in ("full", "window"):
                     c_ = layer_c[f"blk{pi}"]
-                    y, (ck, cv) = L.attn_decode(
+                    y, (kn, vn) = L.attn_decode(
                         p_["attn"], cfg, xx, c_["k"], c_["v"], pos,
                         blk.window, ctx)
                     xx = xx + y
-                    new_c[f"blk{pi}"] = {"k": ck, "v": cv}
+                    if ctx.enabled:
+                        new_c[f"blk{pi}"] = {
+                            "k": L.select_token(c_["k"], kn, pos),
+                            "v": L.select_token(c_["v"], vn, pos)}
+                    else:
+                        rows[f"blk{pi}"] = {"k": kn, "v": vn}
                 elif blk.mixer == "mla":
                     c_ = layer_c[f"blk{pi}"]
                     y, (cc, kr) = L.mla_decode(
@@ -423,14 +432,19 @@ def decode_step(params, cfg: ModelConfig, cache, tokens,
                     y, _ = L.moe_forward(p_["ffn"], cfg, xx, ctx)
                     xx = xx + y
             xx = ctx.constraint(xx, bspec, None, None)
-            cache_full = jax.tree.map(
-                lambda a, nc: lax.dynamic_update_index_in_dim(a, nc, i, 0),
-                cache_full, new_c)
-            return (xx, cache_full), None
+            cache_full = {**cache_full, **{
+                key: jax.tree.map(
+                    lambda a, n: lax.dynamic_update_index_in_dim(a, n, i, 0),
+                    cache_full[key], nc)
+                for key, nc in new_c.items()}}
+            return (xx, cache_full), rows
 
         idx = jnp.arange(stage.repeat)
-        (x, new_sc), _ = lax.scan(body, (x, sc), (idx, sp))
-        new_stage_caches.append(new_sc)
+        (x, new_sc), rows = lax.scan(body, (x, sc), (idx, sp))
+        new_stage_caches.append({**new_sc, **{
+            key: jax.tree.map(lambda a, n: L.write_token_rows(a, n, pos),
+                              new_sc[key], r)
+            for key, r in rows.items()}})
 
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = logits_fn(params, cfg, x[:, 0])

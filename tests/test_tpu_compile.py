@@ -12,6 +12,7 @@ test file.
 """
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -156,3 +157,18 @@ def test_smoke_decode_fits_one_chip(one_chip):
                             ctx=NULL_CTX).compile()
     assert compiled.memory_analysis().alias_size_in_bytes > 0   # donated
     assert _total_bytes(compiled) < V5E_USABLE_BYTES
+
+
+def test_minicpm_decode_keeps_one_cache_buffer(one_chip, minicpm_2_layers):
+    """The decode stores each new token's K/V rows into the donated cache
+    in place: no copy of the stacked cache, in any layout, is made."""
+    cfg = minicpm_2_layers
+    params, cache = _model_shapes(cfg, one_chip, CHIP_BATCH, CHIP_MAX_LEN)
+    toks = _spec(one_chip, (CHIP_BATCH, 1), jnp.int32)
+    compiled = DECODE.lower(params, cache, toks, cfg=cfg,
+                            ctx=NULL_CTX).compile()
+    assert compiled.memory_analysis().alias_size_in_bytes > 0   # donated
+    stack = ",".join(map(str, cache["stages"][0]["blk0"]["k"].shape))
+    copies = re.findall(rf"= f32\[{stack}\]\{{[^}}]*\}} copy\(",
+                        compiled.as_text())
+    assert not copies, copies
