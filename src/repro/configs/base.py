@@ -54,6 +54,26 @@ class MoEConfig:
     d_ff_shared: int = 0          # hidden dim of the fused shared expert
     router_aux_weight: float = 0.01
     capacity_factor: float = 2.0  # per-expert slots = ceil(T*k*cf/E)
+    # the experts this chip holds, ids [0, experts_held), under expert
+    # parallelism (None: all of them); the router keeps all num_experts
+    experts_held: Optional[int] = None
+    norm_topk_prob: bool = True   # renormalise the top-k routing weights
+
+    @property
+    def held(self) -> int:
+        return self.num_experts if self.experts_held is None \
+            else self.experts_held
+
+
+@dataclasses.dataclass(frozen=True)
+class YaRN:
+    """YaRN rotary scaling as DeepSeek-V2 defines it (arXiv:2309.00071)."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -62,6 +82,7 @@ class MLAConfig:
     rope_head_dim: int = 64
     nope_head_dim: int = 128
     v_head_dim: int = 128
+    yarn: Optional[YaRN] = None   # rope scaling of q_pe / k_pe
 
 
 @dataclasses.dataclass(frozen=True)
@@ -184,7 +205,7 @@ class ModelConfig:
                 mo = self.moe
                 n += d
                 n += d * mo.num_experts  # router
-                e = mo.num_experts if not active_only else mo.top_k
+                e = mo.held if not active_only else mo.top_k
                 n += 3 * d * mo.d_ff_expert * e
                 if mo.num_shared:
                     n += 3 * d * mo.d_ff_shared
@@ -260,11 +281,12 @@ def reduce_config(cfg: ModelConfig, *, layers_per_stage: int = 1,
                         d_ff_expert=d_ff // 2,
                         num_shared=min(cfg.moe.num_shared, 1),
                         d_ff_shared=d_ff // 2 if cfg.moe.num_shared else 0,
-                        capacity_factor=float(ne))  # no drops in smoke tests
+                        capacity_factor=float(ne),  # no drops in smoke tests
+                        norm_topk_prob=cfg.moe.norm_topk_prob)
     mla = None
     if cfg.mla is not None:
         mla = MLAConfig(kv_lora_rank=32, rope_head_dim=16, nope_head_dim=head_dim,
-                        v_head_dim=head_dim)
+                        v_head_dim=head_dim, yarn=cfg.mla.yarn)
     ssm = None
     if cfg.ssm is not None:
         ssm = SSMConfig(d_state=16, d_conv=4, expand=2, head_dim=16,

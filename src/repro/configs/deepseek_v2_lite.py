@@ -10,7 +10,7 @@ routed experts, matching the "64e" header, so we implement 64.
 import dataclasses
 
 from repro.configs.base import (BlockSpec, MLAConfig, ModelConfig, MoEConfig,
-                                Stage)
+                                Stage, YaRN)
 
 CONFIG = ModelConfig(
     name="deepseek-v2-lite-16b",
@@ -26,10 +26,14 @@ CONFIG = ModelConfig(
         Stage(pattern=(BlockSpec("mla", "dense"),), repeat=1),
         Stage(pattern=(BlockSpec("mla", "moe"),), repeat=26),
     ),
+    # softmax over the 64, greedy top-6 not renormalised, scaling 1
     moe=MoEConfig(num_experts=64, top_k=6, d_ff_expert=1408,
-                  num_shared=2, d_ff_shared=2816),
+                  num_shared=2, d_ff_shared=2816, norm_topk_prob=False),
     mla=MLAConfig(kv_lora_rank=512, rope_head_dim=64, nope_head_dim=128,
-                  v_head_dim=128),
+                  v_head_dim=128,
+                  yarn=YaRN(factor=40.0, original_max_position=4096,
+                            beta_fast=32.0, beta_slow=1.0, mscale=0.707,
+                            mscale_all_dim=0.707)),
     rope_theta=10000.0,
     act="silu",
     source="arXiv:2405.04434",

@@ -71,6 +71,9 @@ class EngineRequest:
     t_submit: Optional[float] = None
     t_admit: Optional[float] = None
     t_first: Optional[float] = None
+    # (decode token, MoE layer, top-k pick) triples of this request that
+    # fell on an expert this chip holds (models with MoE layers)
+    held_routes: int = 0
 
     @property
     def generated(self) -> List[int]:
@@ -269,23 +272,27 @@ class InferenceEngine:
             t0 = time.perf_counter()
             toks = self._to_device(toks)
         with TraceAnnotation("engine.decode", dev=self._dev):
-            logits, nxt, self.cache = self._decode(self.params, self.cache,
+            logits, out, self.cache = self._decode(self.params, self.cache,
                                                    toks)
         with TraceAnnotation("engine.sync", dev=self._dev):
-            nxt = np.asarray(nxt)
+            out = np.asarray(out)
         self.events.append(("decode", len(active),
                             time.perf_counter() - t0))
         with TraceAnnotation("engine.commit", dev=self._dev):
-            self._commit(active, logits, nxt)
+            self._commit(active, logits, out)
         return len(active)
 
-    def _commit(self, active: List[int], logits, nxt: np.ndarray):
-        """Append each active request's next token, retire the finished
-        ones and copy the kept logits rows."""
+    def _commit(self, active: List[int], logits, out: np.ndarray):
+        """Append each active request's next token, count its held
+        routes, retire the finished ones and copy the kept logits rows.
+        ``out`` is the decode's host transfer (``engine_decode``)."""
+        nxt, held = (out[0], out[1]) if out.ndim == 2 else (out, None)
         kept = (np.asarray(logits)
                 if any(self.slots[i].keep_logits for i in active) else None)
         for i in active:
             req = self.slots[i]
+            if held is not None:
+                req.held_routes += int(held[i])
             if req.keep_logits:
                 req.logits.append(kept[i])
             req.tokens.append(int(nxt[i]))
@@ -307,10 +314,15 @@ class InferenceEngine:
 
 def engine_decode(params, cache, tokens, *, cfg: ModelConfig,
                   ctx: ShardCtx = NULL_CTX):
-    """One decode iteration plus its greedy pick: (logits [B, V],
-    next tokens [B], new cache)."""
-    logits, cache = decode_step(params, cfg, cache, tokens, ctx=ctx)
-    return logits, jnp.argmax(logits, axis=-1), cache
+    """One decode iteration plus its greedy pick: (logits [B, V], out,
+    new cache).  ``out`` is what the host reads: the next tokens [B]; for
+    a model with MoE layers [2, B], the next tokens over each slot's held
+    routes (``decode_step``), so that both cross in one transfer."""
+    logits, cache, held = decode_step(params, cfg, cache, tokens, ctx=ctx)
+    out = jnp.argmax(logits, axis=-1)
+    if held is not None:
+        out = jnp.stack([out, held])
+    return logits, out, cache
 
 
 def engine_prefill(params, tokens, *, cfg: ModelConfig, max_len: int,

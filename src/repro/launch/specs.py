@@ -166,7 +166,7 @@ def build_cell(arch: str, shape_name: str, mesh, *,
     }
 
     def serve_step(params, cache, tokens):
-        return decode_step(params, cfg, cache, tokens, ctx=ctx)
+        return decode_step(params, cfg, cache, tokens, ctx=ctx)[:2]
 
     out_shardings = (NamedSharding(mesh, _logits_spec(cfg, mesh, B,
                                                       data_axes)), c_named)

@@ -1,5 +1,6 @@
-"""Core layers: norms, RoPE, GQA attention (full / sliding-window), MLA,
-gated FFN, and sort-based MoE dispatch (ragged grouped GEMM).
+"""Core layers: norms, RoPE (plain and YaRN), GQA attention (full /
+sliding-window), MLA, gated FFN, and MoE dispatch over the experts a chip
+holds.
 
 Every layer has a full-sequence path (train / prefill) and a single-token
 decode path operating on an explicit KV/state cache, so the serving engine
@@ -8,15 +9,15 @@ and the training loop share one parameterization.
 from __future__ import annotations
 
 import math
-from functools import partial
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.configs.base import MLAConfig, ModelConfig, MoEConfig
+from repro.configs.base import MLAConfig, ModelConfig, MoEConfig, YaRN
 from repro.distributed.context import NULL_CTX, ShardCtx
 from repro.models.grouped_gemm import grouped_gemm
 
@@ -35,17 +36,55 @@ def _act(name: str):
     return jax.nn.gelu if name == "gelu" else jax.nn.silu
 
 
-def rope(x, positions, theta: float):
-    """Rotary embedding.  x: [..., L, H, hd]; positions: [..., L]."""
+def rope(x, positions, theta: float, inv_freq=None):
+    """Rotary embedding.  x: [..., L, H, hd]; positions: [..., L].
+    Dimension i pairs with i + hd/2 and turns at ``inv_freq[i]``
+    (default theta^(-i / (hd/2)))."""
     hd = x.shape[-1]
     half = hd // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    freqs = (theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+             if inv_freq is None else jnp.asarray(inv_freq, jnp.float32))
     ang = positions[..., None].astype(jnp.float32) * freqs          # [..., L, half]
     cos = jnp.cos(ang)[..., None, :]                                # [..., L, 1, half]
     sin = jnp.sin(ang)[..., None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
+
+
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, y: YaRN) -> np.ndarray:
+    """DeepSeek-V2's YaRN frequencies for a rotary dimension ``dim``: the
+    interpolated frequencies (theta^(-2i/dim) / factor) for the slow
+    dimensions, the original ones for the fast, and a linear ramp between
+    the dimensions that turn ``beta_fast`` and ``beta_slow`` times over
+    the original context."""
+    if _yarn_mscale(y.factor, y.mscale) != _yarn_mscale(y.factor,
+                                                        y.mscale_all_dim):
+        # DeepSeek-V2 scales cos and sin by their ratio; it is 1 for
+        # every published DeepSeek-V2 configuration, and not applied here
+        raise NotImplementedError("YaRN with mscale != mscale_all_dim")
+    extra = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def turns(rot):
+        return (dim * math.log(y.original_max_position / (rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(turns(y.beta_fast)), 0)
+    high = min(math.ceil(turns(y.beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return (extra / y.factor * ramp + extra * (1 - ramp)).astype(np.float32)
+
+
+def mla_softmax_scale(m: MLAConfig) -> float:
+    """q_head_dim^-0.5, times mscale(factor, mscale_all_dim)^2 under YaRN."""
+    s = (m.nope_head_dim + m.rope_head_dim) ** -0.5
+    if m.yarn is not None and m.yarn.mscale_all_dim:
+        s *= _yarn_mscale(m.yarn.factor, m.yarn.mscale_all_dim) ** 2
+    return s
 
 
 def _normal(key, shape, std, dtype):
@@ -216,27 +255,28 @@ def attn_decode(p, cfg: ModelConfig, x, cache_k, cache_v, pos,
 
 
 def select_token(cache, new, pos):
-    """``cache`` [B,S,KV,hd] with row b's slot ``pos[b] % S`` replaced by
-    ``new`` [B,1,KV,hd]: a masked select over the whole cache.  It is
+    """``cache`` [B,S,...] with row b's slot ``pos[b] % S`` replaced by
+    ``new`` [B,1,...]: a masked select over the whole cache.  It is
     elementwise along a (possibly sequence-sharded) S, so GSPMD never falls
     back to its "involuntary full rematerialization" replication path."""
     S = cache.shape[1]
-    hit = (jnp.arange(S)[None, :] == (pos % S)[:, None])[..., None, None]
+    hit = (jnp.arange(S)[None, :] == (pos % S)[:, None])[
+        (...,) + (None,) * (cache.ndim - 2)]
     return jnp.where(hit, new, cache)
 
 
 def write_token_rows(cache, new, pos):
-    """Write ``new`` [..., B,1,KV,hd] into ``cache`` [..., B,S,KV,hd] at
+    """Write ``new`` [R,B,1,...] into a stage's stacked cache ``cache``
+    [R,B,S,...] (layers, slots, positions, then any trailing shape) at
     row b's slot ``pos[b] % S``, in place: one dynamic_update_slice per
     row, which writes B tokens and keeps the cache's layout (a scatter
     made the TPU compiler relay out and copy the whole cache)."""
-    lead = cache.ndim - 4
-    B, S = cache.shape[lead], cache.shape[lead + 1]
+    B, S = cache.shape[1], cache.shape[2]
     slot = pos % S
     for b in range(B):
         cache = lax.dynamic_update_slice(
-            cache, lax.slice_in_dim(new, b, b + 1, axis=lead),
-            (0,) * lead + (b, slot[b], 0, 0))
+            cache, lax.slice_in_dim(new, b, b + 1, axis=1),
+            (0, b, slot[b]) + (0,) * (cache.ndim - 3))
     return cache
 
 
@@ -295,23 +335,33 @@ def init_mla(key, cfg: ModelConfig, dtype):
 
 
 def _mla_q_and_latent(p, cfg: ModelConfig, h, positions):
+    """Queries (no q LoRA) and the latent: (q_nope, q_rope, c_kv, k_rope),
+    the rope parts turned at the YaRN frequencies where the model has
+    them.  The rotation pairs dimension i with i + rope_dim/2, where
+    DeepSeek-V2 pairs adjacent dimensions: the same model under a fixed
+    permutation of the rope columns of ``wq`` and ``w_dkv``."""
     m: MLAConfig = cfg.mla
     B, L, _ = h.shape
     H = cfg.num_heads
+    inv = (None if m.yarn is None
+           else yarn_inv_freq(m.rope_head_dim, cfg.rope_theta, m.yarn))
     q = (h @ p["wq"]).reshape(B, L, H, m.nope_head_dim + m.rope_head_dim)
     q_nope, q_rope = jnp.split(q, [m.nope_head_dim], axis=-1)
-    q_rope = rope(q_rope, positions, cfg.rope_theta)
+    q_rope = rope(q_rope, positions, cfg.rope_theta, inv)
     dkv = h @ p["w_dkv"]
     c_kv, k_rope = jnp.split(dkv, [m.kv_lora_rank], axis=-1)
     c_kv = rms_norm(c_kv, p["kv_norm"], cfg.norm_eps)
-    k_rope = rope(k_rope[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
+    k_rope = rope(k_rope[:, :, None, :], positions, cfg.rope_theta,
+                  inv)[:, :, 0]
     return q_nope, q_rope, c_kv, k_rope
 
 
+@jax.named_scope("mla")
 def mla_forward(p, cfg: ModelConfig, x, positions, ctx: ShardCtx = NULL_CTX,
                 chunk: int = 512):
     """Full-sequence MLA (expanded form), scanned over query chunks so peak
-    score memory is [B, H, chunk, L].  Returns (y, (c_kv, k_rope))."""
+    score memory is [B, H, chunk, L]; a ragged tail pads the queries only.
+    Returns (y, (c_kv, k_rope))."""
     m: MLAConfig = cfg.mla
     B, L, _ = x.shape
     H = cfg.num_heads
@@ -319,7 +369,7 @@ def mla_forward(p, cfg: ModelConfig, x, positions, ctx: ShardCtx = NULL_CTX,
     q_nope, q_rope, c_kv, k_rope = _mla_q_and_latent(p, cfg, h, positions)
     k_nope = (c_kv @ p["w_uk"]).reshape(B, L, H, m.nope_head_dim)
     v = (c_kv @ p["w_uv"]).reshape(B, L, H, m.v_head_dim)
-    scale = (m.nope_head_dim + m.rope_head_dim) ** -0.5
+    scale = mla_softmax_scale(m)
     bspec = ctx.batch_spec_entry(B)
     hspec = ctx.heads_spec(H, m.nope_head_dim)
     if hspec is not None:
@@ -338,10 +388,12 @@ def mla_forward(p, cfg: ModelConfig, x, positions, ctx: ShardCtx = NULL_CTX,
     if L <= chunk:
         y = attend(q_nope, q_rope, causal_mask(L, L))
     else:
-        assert L % chunk == 0, (L, chunk)
-        n = L // chunk
-        qn = q_nope.reshape(B, n, chunk, H, -1).transpose(1, 0, 2, 3, 4)
-        qr = q_rope.reshape(B, n, chunk, H, -1).transpose(1, 0, 2, 3, 4)
+        n = -(-L // chunk)
+        pad = ((0, 0), (0, n * chunk - L), (0, 0), (0, 0))
+        qn = jnp.pad(q_nope, pad).reshape(B, n, chunk, H, -1) \
+            .transpose(1, 0, 2, 3, 4)
+        qr = jnp.pad(q_rope, pad).reshape(B, n, chunk, H, -1) \
+            .transpose(1, 0, 2, 3, 4)
         offs = jnp.arange(n) * chunk
 
         def body(_, inp):
@@ -352,11 +404,13 @@ def mla_forward(p, cfg: ModelConfig, x, positions, ctx: ShardCtx = NULL_CTX,
 
         _, y = lax.scan(jax.checkpoint(body, prevent_cse=False), None,
                         (qn, qr, offs))
-        y = y.transpose(1, 0, 2, 3, 4).reshape(B, L, H, m.v_head_dim)
+        y = y.transpose(1, 0, 2, 3, 4).reshape(
+            B, n * chunk, H, m.v_head_dim)[:, :L]
     y = y.reshape(B, L, H * m.v_head_dim)
     return y @ p["wo"], (c_kv, k_rope)
 
 
+@jax.named_scope("mla")
 def mla_decode(p, cfg: ModelConfig, x, cache_ckv, cache_krope, pos,
                ctx: ShardCtx = NULL_CTX):
     """One-token MLA decode with matrix absorption: scores and values are
@@ -364,19 +418,22 @@ def mla_decode(p, cfg: ModelConfig, x, cache_ckv, cache_krope, pos,
     O(L * (kv_lora + rope_dim)) instead of O(L * H * head_dim).
 
     cache_ckv: [B,S,kv_lora]; cache_krope: [B,S,rope_dim]; pos: [B].
+    Attention reads the cache with the new token selected in at its slot
+    (``select_token``).  Returns (y, (c_new [B,1,kv_lora], kr_new
+    [B,1,rope_dim])), the token's latent rows, which ``model.decode_step``
+    stores as it stores ``attn_decode``'s.
     """
     m: MLAConfig = cfg.mla
-    B, S = cache_ckv.shape[0], cache_ckv.shape[1]
-    H = cfg.num_heads
+    S = cache_ckv.shape[1]
+    B, H = x.shape[0], cfg.num_heads
     h = rms_norm(x, p["ln"], cfg.norm_eps)
     q_nope, q_rope, c_new, kr_new = _mla_q_and_latent(p, cfg, h, pos[:, None])
-    hit = (jnp.arange(S)[None, :] == pos[:, None])[..., None]
-    cache_ckv = jnp.where(hit, c_new[:, 0][:, None], cache_ckv)
-    cache_krope = jnp.where(hit, kr_new[:, 0][:, None], cache_krope)
+    cache_ckv = select_token(cache_ckv, c_new, pos)
+    cache_krope = select_token(cache_krope, kr_new, pos)
     # absorb w_uk into q:  q_lat[b,h,r] = sum_d q_nope[b,h,d] * w_uk[r, h*d]
     w_uk = p["w_uk"].reshape(m.kv_lora_rank, H, m.nope_head_dim)
     q_lat = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0], w_uk)
-    scale = (m.nope_head_dim + m.rope_head_dim) ** -0.5
+    scale = mla_softmax_scale(m)
     scores = (jnp.einsum("bhr,bsr->bhs", q_lat, cache_ckv)
               + jnp.einsum("bhd,bsd->bhs", q_rope[:, 0], cache_krope)
               ).astype(jnp.float32) * scale
@@ -386,7 +443,7 @@ def mla_decode(p, cfg: ModelConfig, x, cache_ckv, cache_krope, pos,
     out_lat = jnp.einsum("bhs,bsr->bhr", probs, cache_ckv)
     w_uv = p["w_uv"].reshape(m.kv_lora_rank, H, m.v_head_dim)
     y = jnp.einsum("bhr,rhd->bhd", out_lat, w_uv).reshape(B, 1, H * m.v_head_dim)
-    return y @ p["wo"], (cache_ckv, cache_krope)
+    return y @ p["wo"], (c_new, kr_new)
 
 
 # ---------------------------------------------------------------------------
@@ -415,19 +472,21 @@ def ffn_forward(p, cfg: ModelConfig, x, ctx: ShardCtx = NULL_CTX):
 
 
 # ---------------------------------------------------------------------------
-# MoE (sort-based dispatch + ragged grouped GEMM)
+# MoE: the router over every expert, the experts this chip holds
 # ---------------------------------------------------------------------------
 
 def init_moe(key, cfg: ModelConfig, dtype):
+    """The router keeps all ``num_experts`` outputs; the expert weights are
+    the held ones' (ids [0, held))."""
     mo: MoEConfig = cfg.moe
     d, E, ff = cfg.d_model, mo.num_experts, mo.d_ff_expert
     ks = jax.random.split(key, 5)
     p = {
         "ln": jnp.zeros((d,), dtype),
         "router": _normal(ks[0], (d, E), d ** -0.5, jnp.float32),
-        "w_gate": _normal(ks[1], (E, d, ff), d ** -0.5, dtype),
-        "w_up": _normal(ks[2], (E, d, ff), d ** -0.5, dtype),
-        "w_down": _normal(ks[3], (E, ff, d), ff ** -0.5, dtype),
+        "w_gate": _normal(ks[1], (mo.held, d, ff), d ** -0.5, dtype),
+        "w_up": _normal(ks[2], (mo.held, d, ff), d ** -0.5, dtype),
+        "w_down": _normal(ks[3], (mo.held, ff, d), ff ** -0.5, dtype),
     }
     if mo.num_shared:
         sh = init_ffn(ks[4], cfg, dtype, d_ff=mo.d_ff_shared)
@@ -440,15 +499,40 @@ def moe_capacity(T: int, K: int, E: int, cf: float) -> int:
     return int(min(T * K, max(-(-T * K * cf // E), 16)))
 
 
+def _route(p, mo: MoEConfig, xt):
+    """Softmax over every expert's logit, greedy top-k: (probs [T, E],
+    weights [T, K], expert ids [T, K]); the weights renormalised to sum
+    to 1 where ``mo.norm_topk_prob``.  The logits are float32 products,
+    as DeepSeek-V2 computes its gate: at the TPU's default precision
+    bfloat16 products would change which experts a token picks."""
+    logits = jnp.matmul(xt.astype(jnp.float32), p["router"],
+                        precision=lax.Precision.HIGHEST)       # [T, E]
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top_i = lax.top_k(probs, mo.top_k)                  # [T, K]
+    if mo.norm_topk_prob:
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    return probs, top_p, top_i
+
+
+def _aux_loss(probs, top_i, E: int):
+    """Switch-style load-balancing loss."""
+    frac_tokens = jnp.mean(jax.nn.one_hot(top_i, E, dtype=jnp.float32),
+                           axis=(0, 1))
+    frac_probs = jnp.mean(probs, axis=0)
+    return E * jnp.sum(frac_tokens * frac_probs)
+
+
 def _moe_local(p, cfg: ModelConfig, xt, act, axis_name: Optional[str] = None):
-    """Sort + capacity-dispatch MoE over local tokens xt [T, d].
+    """Sort + capacity-dispatch MoE over local tokens xt [T, d], every
+    expert held.
 
     Tokens are sorted by expert and scattered into [E, C, d] slots
     (C = capacity per expert); expert GEMMs are batched einsums.  This is
     the GShard/MaxText formulation: peak memory is O(T*K*cf*d) and the
     XLA graph contains no data-dependent dense expansions — unlike
-    ``lax.ragged_dot``, whose one-hot decomposition materializes
-    [E, T*K, d] (observed 640 GB/device on deepseek-v2-lite train_4k).
+    ``lax.ragged_dot`` on a backend without a grouped-matmul kernel, whose
+    one-hot decomposition materializes [E, T*K, d] (observed 640 GB/device
+    on deepseek-v2-lite train_4k).
     Tokens beyond capacity are dropped (standard; the aux loss keeps
     routing balanced so drops are rare at cf=2).
 
@@ -459,11 +543,9 @@ def _moe_local(p, cfg: ModelConfig, xt, act, axis_name: Optional[str] = None):
     mo: MoEConfig = cfg.moe
     T, d = xt.shape
     E, K = mo.num_experts, mo.top_k
+    assert mo.held == E, "the capacity dispatch computes every expert"
     C = moe_capacity(T, K, E, mo.capacity_factor)
-    logits = (xt.astype(jnp.float32) @ p["router"])            # [T, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_i = lax.top_k(probs, K)                         # [T, K]
-    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    probs, top_p, top_i = _route(p, mo, xt)
 
     flat_e = top_i.reshape(-1)                                 # [M = T*K]
     M = T * K
@@ -497,20 +579,68 @@ def _moe_local(p, cfg: ModelConfig, xt, act, axis_name: Optional[str] = None):
     inv_order = jnp.argsort(order)
     out_tk = jnp.take(out_sorted, inv_order, axis=0).reshape(T, K, d)
     y = jnp.einsum("tkd,tk->td", out_tk, top_p.astype(out_tk.dtype))
-    # Switch-style load-balancing aux loss
-    frac_tokens = jnp.mean(jax.nn.one_hot(top_i, E, dtype=jnp.float32),
-                           axis=(0, 1))
-    frac_probs = jnp.mean(probs, axis=0)
-    aux = E * jnp.sum(frac_tokens * frac_probs)
-    return y, aux
+    return y, _aux_loss(probs, top_i, E)
 
 
+def _moe_held(p, cfg: ModelConfig, xt, act, one_token: bool):
+    """The held experts' part of the routed result for tokens xt [T, d],
+    with no token dropped: what a token gets does not depend on what else
+    is in the batch.  Picks of experts this chip does not hold add
+    nothing (their chips add them).
+
+    - ``one_token`` (a decode step, a few tokens): every held expert
+      computes every token, weighted by its routing weight or 0, so each
+      held expert's weights are read once, the least a step reads;
+    - otherwise (a prompt): the picks that fall on held experts, sorted by
+      expert, go through one grouped matmul per weight (``grouped_gemm``:
+      ``lax.ragged_dot``, a grouped-matmul kernel on a TPU); the other
+      picks sort past the last group.
+
+    Returns (y [T, d], aux_loss, held [T] int32: the token's picks that
+    fell on a held expert).
+    """
+    mo: MoEConfig = cfg.moe
+    T, d = xt.shape
+    Eh, K = mo.held, mo.top_k
+    probs, top_p, top_i = _route(p, mo, xt)
+    on = top_i < Eh                                            # [T, K]
+    w = jnp.where(on, top_p, 0.0)
+    if one_token:
+        w_te = jnp.einsum("tk,tke->te", w, jax.nn.one_hot(
+            top_i, Eh, dtype=w.dtype))                         # [T, Eh]
+        gate = jnp.einsum("td,edf->etf", xt, p["w_gate"])
+        up = jnp.einsum("td,edf->etf", xt, p["w_up"])
+        out = jnp.einsum("etf,efd->etd", act(gate) * up, p["w_down"])
+        y = jnp.einsum("etd,te->td", out, w_te.astype(out.dtype))
+    else:
+        flat_e = jnp.where(on, top_i, Eh).reshape(-1)          # [M = T*K]
+        order = jnp.argsort(flat_e)
+        sizes = jnp.bincount(flat_e, length=Eh + 1)[:Eh].astype(jnp.int32)
+        xs = jnp.take(xt, order // K, axis=0)                  # [M, d]
+        gate = grouped_gemm(xs, p["w_gate"], sizes)
+        up = grouped_gemm(xs, p["w_up"], sizes)
+        out = grouped_gemm(act(gate) * up, p["w_down"], sizes)
+        w_sorted = jnp.take(w.reshape(-1), order)
+        live = jnp.arange(T * K) < jnp.sum(sizes)              # in a group
+        out = jnp.where(live[:, None],
+                        out * w_sorted[:, None].astype(out.dtype), 0.0)
+        y = jnp.take(out, jnp.argsort(order), axis=0) \
+            .reshape(T, K, d).sum(axis=1)
+    held = jnp.sum(on, axis=-1, dtype=jnp.int32)
+    return y, _aux_loss(probs, top_i, mo.num_experts), held
+
+
+@jax.named_scope("moe")
 def moe_forward(p, cfg: ModelConfig, x, ctx: ShardCtx = NULL_CTX):
-    """x: [B, L, d] -> (y, aux_loss).
+    """x: [B, L, d] -> (y, aux_loss, held [B, L] int32).
 
-    With an active mesh the dispatch runs under shard_map: tokens stay
-    local to their (pod, data) shard (routing is per-token), expert ff
-    dims are TP-sharded over the model axis, and only the O(T_local x d)
+    ``held`` counts each token's top-k picks that fell on an expert this
+    chip holds.  With no mesh (every served engine) the held experts'
+    part is computed with no token dropped (``_moe_held``), plus the
+    shared experts for every token.  Under a mesh every expert is held
+    and the capacity dispatch runs: under shard_map, tokens stay local to
+    their (pod, data) shard (routing is per-token), expert ff dims are
+    TP-sharded over the model axis, and only the O(T_local x d)
     down-projection psum crosses model-axis links.
     """
     B, L, d = x.shape
@@ -555,13 +685,18 @@ def moe_forward(p, cfg: ModelConfig, x, ctx: ShardCtx = NULL_CTX):
             out_specs=(P(bspec, None, None), P()),
             check_vma=False,
         )(h, pp)
-        return y, aux
-    # plain path (smoke tests, decode, tiny batches)
+        return y, aux, jnp.full((B, L), mo.top_k, jnp.int32)
     h = rms_norm(x, p["ln"], cfg.norm_eps)
-    y, aux = _moe_local(p, cfg, h.reshape(-1, d), act)
+    if ctx.enabled:
+        y, aux = _moe_local(p, cfg, h.reshape(-1, d), act)
+        held = jnp.full((B, L), mo.top_k, jnp.int32)
+    else:
+        y, aux, held = _moe_held(p, cfg, h.reshape(-1, d), act,
+                                 one_token=L == 1)
+        held = held.reshape(B, L)
     y = y.reshape(B, L, d)
     if "shared" in p:
         sp = p["shared"]
         zs = act(h @ sp["w_gate"]) * (h @ sp["w_up"])
         y = y + zs @ sp["w_down"]
-    return y, aux
+    return y, aux, held

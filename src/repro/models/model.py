@@ -114,7 +114,7 @@ def _apply_block(blk: BlockSpec, p, cfg: ModelConfig, x, positions,
     if blk.ffn == "dense":
         x = x + L.ffn_forward(p["ffn"], cfg, x, ctx)
     elif blk.ffn == "moe":
-        y, a = L.moe_forward(p["ffn"], cfg, x, ctx)
+        y, a, _ = L.moe_forward(p["ffn"], cfg, x, ctx)
         x = x + y
         aux = aux + a
     bspec = ctx.batch_spec_entry(B)
@@ -326,7 +326,7 @@ def prefill_chunk(params, cfg: ModelConfig, cache, tokens, n_valid=None,
                 if blk.ffn == "dense":
                     xx = xx + L.ffn_forward(p_["ffn"], cfg, xx, ctx)
                 elif blk.ffn == "moe":
-                    y2, _ = L.moe_forward(p_["ffn"], cfg, xx, ctx)
+                    y2, _, _ = L.moe_forward(p_["ffn"], cfg, xx, ctx)
                     xx = xx + y2
             xx = ctx.constraint(xx, bspec, ctx.seq_entry(C), None)
             cache_full = jax.tree.map(
@@ -373,12 +373,16 @@ def ring_convert_cache(cfg: ModelConfig, cache, max_len: int, length: int):
 def decode_step(params, cfg: ModelConfig, cache, tokens,
                 ctx: ShardCtx = NULL_CTX):
     """One decode iteration.  tokens: [B, 1] int32.  Returns
-    (logits [B, V], new_cache)."""
+    (logits [B, V], new_cache, held): ``held`` [B] int32 counts each
+    slot's (MoE layer, top-k pick) pairs that fell on an expert this chip
+    holds, None for a model without MoE layers."""
     pos = cache["pos"]
     x = jnp.take(params["embed"], tokens, axis=0)
     B = x.shape[0]
     bspec = ctx.batch_spec_entry(B)
     x = ctx.constraint(x, bspec, None, None)
+    held = (jnp.zeros((B,), jnp.int32)
+            if any(b.ffn == "moe" for b in cfg.layer_list()) else None)
 
     new_stage_caches = []
     for si, stage in enumerate(cfg.stages):
@@ -388,18 +392,26 @@ def decode_step(params, cfg: ModelConfig, cache, tokens,
         # the cache rides the scan CARRY and is updated in place at the
         # layer index — XLA aliases while-loop carries, so decode keeps a
         # single cache buffer instead of stacked xs/ys copies (which cost
-        # +2x cache per k/v at 32k contexts).  Attention layers store
-        # their new token as ``L.attn_decode`` says: under a mesh by a
-        # select into the layer slice inside the scan; with no mesh the
-        # scan only reads the cache and emits the token's rows, which
+        # +2x cache per k/v at 32k contexts).  Attention and MLA layers
+        # store their new token as ``L.attn_decode`` says: under a mesh
+        # by a select into the layer slice inside the scan; with no mesh
+        # the scan only reads the cache and emits the token's rows, which
         # ``L.write_token_rows`` writes after it.
         def body(carry, inp, stage=stage):
-            xx, cache_full = carry
+            xx, cache_full, held = carry
             i, layer_p = inp
             layer_c = jax.tree.map(
                 lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
                 cache_full)
             new_c, rows = {}, {}
+
+            def store(key, c_, new):
+                if ctx.enabled:
+                    new_c[key] = {k: L.select_token(c_[k], n, pos)
+                                  for k, n in new.items()}
+                else:
+                    rows[key] = new
+
             for pi, blk in enumerate(stage.pattern):
                 p_ = layer_p[f"blk{pi}"]
                 if blk.mixer in ("full", "window"):
@@ -408,18 +420,13 @@ def decode_step(params, cfg: ModelConfig, cache, tokens,
                         p_["attn"], cfg, xx, c_["k"], c_["v"], pos,
                         blk.window, ctx)
                     xx = xx + y
-                    if ctx.enabled:
-                        new_c[f"blk{pi}"] = {
-                            "k": L.select_token(c_["k"], kn, pos),
-                            "v": L.select_token(c_["v"], vn, pos)}
-                    else:
-                        rows[f"blk{pi}"] = {"k": kn, "v": vn}
+                    store(f"blk{pi}", c_, {"k": kn, "v": vn})
                 elif blk.mixer == "mla":
                     c_ = layer_c[f"blk{pi}"]
-                    y, (cc, kr) = L.mla_decode(
+                    y, (cn, krn) = L.mla_decode(
                         p_["attn"], cfg, xx, c_["ckv"], c_["kr"], pos, ctx)
                     xx = xx + y
-                    new_c[f"blk{pi}"] = {"ckv": cc, "kr": kr}
+                    store(f"blk{pi}", c_, {"ckv": cn, "kr": krn})
                 elif blk.mixer == "mamba":
                     c_ = layer_c[f"blk{pi}"]
                     y, (conv_s, ssm_s) = ssd.mamba_decode(
@@ -429,18 +436,19 @@ def decode_step(params, cfg: ModelConfig, cache, tokens,
                 if blk.ffn == "dense":
                     xx = xx + L.ffn_forward(p_["ffn"], cfg, xx, ctx)
                 elif blk.ffn == "moe":
-                    y, _ = L.moe_forward(p_["ffn"], cfg, xx, ctx)
+                    y, _, h = L.moe_forward(p_["ffn"], cfg, xx, ctx)
                     xx = xx + y
+                    held = held + h[:, 0]
             xx = ctx.constraint(xx, bspec, None, None)
             cache_full = {**cache_full, **{
                 key: jax.tree.map(
                     lambda a, n: lax.dynamic_update_index_in_dim(a, n, i, 0),
                     cache_full[key], nc)
                 for key, nc in new_c.items()}}
-            return (xx, cache_full), rows
+            return (xx, cache_full, held), rows
 
         idx = jnp.arange(stage.repeat)
-        (x, new_sc), rows = lax.scan(body, (x, sc), (idx, sp))
+        (x, new_sc, held), rows = lax.scan(body, (x, sc, held), (idx, sp))
         new_stage_caches.append({**new_sc, **{
             key: jax.tree.map(lambda a, n: L.write_token_rows(a, n, pos),
                               new_sc[key], r)
@@ -449,4 +457,4 @@ def decode_step(params, cfg: ModelConfig, cache, tokens,
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = logits_fn(params, cfg, x[:, 0])
     new_cache = {"stages": new_stage_caches, "pos": pos + 1}
-    return logits, new_cache
+    return logits, new_cache, held

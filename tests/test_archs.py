@@ -48,7 +48,7 @@ def test_forward_and_decode_consistency(arch):
     np.testing.assert_allclose(np.asarray(lg_pf),
                                np.asarray(logits_tf[:, -2]),
                                rtol=2e-3, atol=2e-3)
-    lg_dec, cache = decode_step(params, cfg, cache, toks[:, -1:])
+    lg_dec, cache, _ = decode_step(params, cfg, cache, toks[:, -1:])
     np.testing.assert_allclose(np.asarray(lg_dec),
                                np.asarray(logits_tf[:, -1]),
                                rtol=2e-3, atol=2e-3)
@@ -88,7 +88,7 @@ def test_multi_token_decode_matches_teacher_forcing():
     logits_tf = logits_fn(params, cfg, h)
     _, cache = prefill(params, cfg, toks[:, :S - n_dec], max_len=S + 2)
     for i in range(S - n_dec, S):
-        lg, cache = decode_step(params, cfg, cache, toks[:, i:i + 1])
+        lg, cache, _ = decode_step(params, cfg, cache, toks[:, i:i + 1])
         np.testing.assert_allclose(np.asarray(lg),
                                    np.asarray(logits_tf[:, i]),
                                    rtol=3e-3, atol=3e-3)

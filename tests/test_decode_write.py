@@ -86,7 +86,7 @@ def test_row_write_matches_masked_select(arch):
     cfg = _config(arch)
     params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
     ours = ref = _random_cache(cfg, jax.random.PRNGKey(1))
-    step = jax.jit(lambda p, c, t: decode_step(p, cfg, c, t))
+    step = jax.jit(lambda p, c, t: decode_step(p, cfg, c, t)[:2])
     ref_step = jax.jit(lambda p, c, t: _reference_step(p, cfg, c, t))
     toks = jax.random.randint(jax.random.PRNGKey(2), (N_STEPS, len(POS0), 1),
                               1, cfg.vocab_size)

@@ -110,8 +110,8 @@ def test_moe_shard_map_dispatch_matches_plain_path():
     mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
     p = init_moe(jax.random.PRNGKey(0), cfg, jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, cfg.d_model))
-    y0, aux0 = moe_forward(p, cfg, x)
-    y1, aux1 = jax.jit(
+    y0, aux0, _ = moe_forward(p, cfg, x)
+    y1, aux1, _ = jax.jit(
         lambda p, x: moe_forward(p, cfg, x, ShardCtx(mesh=mesh)))(p, x)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y0),
                                rtol=1e-5, atol=1e-5)

@@ -20,7 +20,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
-from repro.configs.base import uniform_stage
+from repro.configs.base import Stage, uniform_stage
 from repro.distributed.context import NULL_CTX
 from repro.engine.engine import DECODE, engine_prefill
 from repro.kernels.flash_attention.flash_attention import \
@@ -172,3 +172,71 @@ def test_minicpm_decode_keeps_one_cache_buffer(one_chip, minicpm_2_layers):
     copies = re.findall(rf"= f32\[{stack}\]\{{[^}}]*\}} copy\(",
                         compiled.as_text())
     assert not copies, copies
+
+
+@pytest.fixture(scope="module")
+def deepseek_2_layers():
+    """DeepSeek-V2-Lite at published widths: the dense layer 0 and one MoE
+    layer holding 8 of its 64 routed experts."""
+    cfg = get_config("deepseek-v2-lite-16b")
+    dense, moe = (st.pattern for st in cfg.stages)
+    return dataclasses.replace(
+        cfg, num_layers=2, stages=(Stage(dense, 1), Stage(moe, 1)),
+        moe=dataclasses.replace(cfg.moe, experts_held=8))
+
+
+DEEPSEEK_BATCH, DEEPSEEK_MAX_LEN = 32, 2048
+
+
+def test_deepseek_decode_compiles_at_full_width(one_chip, deepseek_2_layers):
+    cfg = deepseek_2_layers
+    params, cache = _model_shapes(cfg, one_chip, DEEPSEEK_BATCH,
+                                  DEEPSEEK_MAX_LEN)
+    toks = _spec(one_chip, (DEEPSEEK_BATCH, 1), jnp.int32)
+    DECODE.lower(params, cache, toks, cfg=cfg, ctx=NULL_CTX).compile()
+
+
+def test_deepseek_prefill_compiles_at_full_width(one_chip, deepseek_2_layers):
+    """A prompt whose length is not a multiple of the attention's query
+    chunk, its held experts in one grouped-matmul kernel."""
+    cfg = deepseek_2_layers
+    params, _ = _model_shapes(cfg, one_chip, 1, DEEPSEEK_MAX_LEN)
+    fn = jax.jit(lambda p, t: engine_prefill(p, t, cfg=cfg,
+                                             max_len=DEEPSEEK_MAX_LEN))
+    compiled = fn.lower(params, _spec(one_chip, (1, 1664), jnp.int32)) \
+        .compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_deepseek_cell_decode_fits_one_chip_and_copies_no_latent_cache(
+        one_chip):
+    """The benchmark cell's decode (14 layers, 8 held experts a layer,
+    batch 32 of 2048 latent positions, cache donated) fits the memory one
+    v5e chip leaves to programs and writes its latent rows in place: no
+    copy of a stacked latent cache is made, in any dtype or layout.  The
+    one other buffer of a stacked latent cache's shape is its bfloat16
+    image, which default precision feeds the matrix unit: the compiler
+    converts the whole stack once, ahead of the layer loop, since the
+    loop only reads it (about 4 ms of a decode step on the chip, the same
+    bytes as converting each layer's slice inside the loop).  A float32
+    or second converted stack fails the test."""
+    import json
+
+    from chipbench.harness import ROOT
+    from chipbench.program import deepseek_v2
+    spec = json.loads((ROOT / "chipbench" / "configs"
+                       / "deepseek-v2-lite.json").read_text())
+    cfg = deepseek_v2.config(spec)
+    B, S = spec["serve"]["max_batch"], spec["serve"]["max_len"]
+    params, cache = _model_shapes(cfg, one_chip, B, S)
+    compiled = DECODE.lower(params, cache, _spec(one_chip, (B, 1), jnp.int32),
+                            cfg=cfg, ctx=NULL_CTX).compile()
+    assert compiled.memory_analysis().alias_size_in_bytes > 0   # donated
+    assert _total_bytes(compiled) < V5E_USABLE_BYTES
+    text = compiled.as_text()
+    for stage in cache["stages"]:
+        for leaf in stage["blk0"].values():
+            stack = ",".join(map(str, leaf.shape))
+            made = re.findall(rf"= (\w+)\[{stack}\]\{{[^}}]*\}} "
+                              rf"(copy|copy-start|convert)\(", text)
+            assert made in ([], [("bf16", "convert")]), made
