@@ -120,7 +120,8 @@ def one_chip_phase(size: Size, device, seed: int) -> Dict:
                              reference_logits(engines[0].params, size.cfg,
                                               by_rid[rid]))
             for rid in range(len(size.prompt_lens))}
-    return {"result": res, "errors": errs}
+    return {"result": res, "errors": errs,
+            "weights": engines[0].weight_bytes}
 
 
 def replicas_phase(size: Size, devices, seed: int) -> Dict:
@@ -170,7 +171,7 @@ def main(argv=None) -> None:
     cut = launcher.DEPTH_CUTS[ARCH]
     print(f"config: {cfg.name} d_model={cfg.d_model} heads={cfg.num_heads}"
           f"x{cfg.head_dim} kv_heads={cfg.num_kv_heads} d_ff={cfg.d_ff} "
-          f"vocab={cfg.vocab_size} dtype=float32, layers {cfg.num_layers} "
+          f"vocab={cfg.vocab_size}, layers {cfg.num_layers} "
           f"of {full.num_layers}: {cut.why}")
     print(f"workload: {N_REQUESTS} requests, prompts {size.prompt_lens} "
           f"tokens, {MAX_NEW} new tokens each, max_batch={size.max_batch} "
@@ -189,6 +190,7 @@ def main(argv=None) -> None:
     else:
         devs = devices[:1]
         out = one_chip_phase(size, devs[0], args.seed)
+        print(f"weight bytes held by dtype: {out['weights']}")
         against = "model_forward"
     res = out["result"]
     worst = max(out["errors"].values())

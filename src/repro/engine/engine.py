@@ -37,6 +37,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import logging
 import time
 from collections import deque
 from typing import Deque, List, Optional
@@ -51,7 +52,9 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs.base import ModelConfig
 from repro.distributed.context import NULL_CTX, ShardCtx
 from repro.models import (decode_step, init_cache, init_params, prefill,
-                          prefill_chunk, ring_convert_cache)
+                          prefill_chunk, ring_convert_cache, serving_params)
+
+log = logging.getLogger(__name__)
 
 
 @dataclasses.dataclass
@@ -86,7 +89,9 @@ class InferenceEngine:
 
     ``device`` (default ``jax.devices()[0]``) holds the params and the
     cache; ``params=None`` initialises them there from ``seed``, so
-    replicas built with one seed serve one model."""
+    replicas built with one seed serve one model.  On a TPU the engine
+    keeps the matmul weights in bfloat16 (``serving_params``) and not the
+    tree it was handed; ``weight_bytes`` says what it holds."""
 
     def __init__(self, cfg: ModelConfig, params=None, *, max_batch: int = 8,
                  max_len: int = 256, ctx: ShardCtx = NULL_CTX, seed: int = 0,
@@ -104,7 +109,21 @@ class InferenceEngine:
             params = jax.jit(
                 lambda k: init_params(cfg, k, dtype=jnp.float32),
                 out_shardings=on_device)(jax.random.PRNGKey(seed))
-        self.params = jax.device_put(params, on_device)
+        params = jax.device_put(params, on_device)
+        if self.device.platform == "tpu":
+            # the TPU's default-precision dots round every matmul weight
+            # to bfloat16: hold them so, rounded once (on the CPU the
+            # float32 product is exact, and the weights stay as given)
+            params = serving_params(params)
+        self.params = params
+        # bytes of weights held, by dtype name: the counter that says
+        # whether the bfloat16 storage is in use
+        self.weight_bytes = {}
+        for a in jax.tree.leaves(params):
+            self.weight_bytes[a.dtype.name] = (
+                self.weight_bytes.get(a.dtype.name, 0) + a.nbytes)
+        log.info("engine on %s holds weights %s", self.device,
+                 self.weight_bytes)
         self.cache = jax.jit(
             lambda: init_cache(cfg, max_batch, max_len, dtype=jnp.float32),
             out_shardings=on_device)()

@@ -80,6 +80,30 @@ def init_params(cfg: ModelConfig, key, dtype=jnp.bfloat16):
     return params
 
 
+# Leaves whose only use is as the weight operand of a default-precision
+# dot.  Every other leaf is a norm gain, the router (multiplied at
+# HIGHEST), an SSD conv weight or decay term, or the embedding table,
+# which is gathered (and, tied, also the logits' weight).
+MATMUL_WEIGHTS = frozenset({
+    "wq", "wk", "wv", "wo",                       # attention
+    "w_dkv", "w_uk", "w_uv",                      # MLA (and its wq, wo)
+    "w_gate", "w_up", "w_down",                   # dense, shared, experts
+    "w_z", "w_x", "w_B", "w_C", "w_dt", "out_proj",   # SSD
+    "lm_head", "prefix_proj",
+})
+
+
+def serving_params(params):
+    """The weights a TPU serves from: every ``MATMUL_WEIGHTS`` leaf in
+    bfloat16, cast where it lies; every other leaf is the array handed
+    over.  A TPU's default-precision dot rounds a float32 weight to
+    bfloat16 at every call; held so, the weight reaches the matrix unit
+    as the same bits, rounded once."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, a: (a.astype(jnp.bfloat16)
+                         if path[-1].key in MATMUL_WEIGHTS else a), params)
+
+
 # ---------------------------------------------------------------------------
 # Train / prefill blocks
 # ---------------------------------------------------------------------------

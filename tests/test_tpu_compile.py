@@ -29,7 +29,8 @@ from repro.kernels.paged_attention.paged_attention import \
     paged_attention_pallas
 from repro.kernels.ssd.ssd import ssd_pallas
 from repro.launch.serve import CHIP_BATCH, CHIP_MAX_LEN, chip_config
-from repro.models import init_cache, init_params
+from repro.models import init_cache, init_params, serving_params
+from repro.models.model import MATMUL_WEIGHTS
 
 # what one v5e chip leaves to programs of its 16 GB of HBM
 V5E_USABLE_BYTES = 15.75e9
@@ -240,3 +241,80 @@ def test_deepseek_cell_decode_fits_one_chip_and_copies_no_latent_cache(
             made = re.findall(rf"= (\w+)\[{stack}\]\{{[^}}]*\}} "
                               rf"(copy|copy-start|convert)\(", text)
             assert made in ([], [("bf16", "convert")]), made
+
+
+# The weights an engine serves from on a TPU (``serving_params``): the
+# dots read the bfloat16 stacks as they are, so no convert of a weight
+# stack, or of one layer's slice of it, is left in either direction.
+
+@pytest.fixture(scope="module")
+def mamba_2_layers():
+    cfg = get_config("mamba2-1.3b")
+    (stage,) = cfg.stages
+    return dataclasses.replace(cfg, num_layers=2,
+                               stages=(Stage(stage.pattern, 2),))
+
+
+@pytest.fixture(scope="module")
+def deepseek_3_layers(deepseek_2_layers):
+    """``deepseek_2_layers`` with two MoE layers: a stage of one layer
+    runs no loop, so nothing could be hoisted out of one."""
+    dense, moe = deepseek_2_layers.stages
+    return dataclasses.replace(deepseek_2_layers, num_layers=3,
+                               stages=(dense, Stage(moe.pattern, 2)))
+
+
+# fixture: (batch, max_len, prompt length of the prefill)
+SERVED = {"minicpm_2_layers": (CHIP_BATCH, CHIP_MAX_LEN, 512),
+          "mamba_2_layers": (32, 2048, 1664),
+          "deepseek_3_layers": (DEEPSEEK_BATCH, DEEPSEEK_MAX_LEN, 1664)}
+
+
+def _compiled_text(cfg, sharding, program, batch, max_len, prompt, served):
+    params, cache = _model_shapes(cfg, sharding, batch, max_len)
+    if served:
+        params = _on(sharding, jax.eval_shape(serving_params, params))
+    if program == "decode":
+        fn = DECODE.lower(params, cache,
+                          _spec(sharding, (batch, 1), jnp.int32),
+                          cfg=cfg, ctx=NULL_CTX)
+    else:
+        fn = jax.jit(lambda p, t: engine_prefill(
+            p, t, cfg=cfg, max_len=max_len)).lower(
+                params, _spec(sharding, (1, prompt), jnp.int32))
+    return params, fn.compile().as_text()
+
+
+def _weight_stack_converts(params, text):
+    """(dtype, shape) of every convert whose result has the shape of a
+    matmul weight stack or of one layer's slice of it; a convert's
+    operand has its result's shape, so both directions are caught."""
+    shapes = set()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(
+            params["stages"])[0]:
+        if path[-1].key in MATMUL_WEIGHTS:
+            shapes |= {",".join(map(str, leaf.shape)),
+                       ",".join(map(str, leaf.shape[1:]))}
+    made = re.findall(r"= (\w+)\[([\d,]*)\]\{[^}]*\} convert\(", text)
+    return [m for m in made if m[1] in shapes]
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("model", sorted(SERVED))
+def test_served_weights_reach_the_dots_unconverted(one_chip, request, model,
+                                                   program):
+    cfg = request.getfixturevalue(model)
+    params, text = _compiled_text(cfg, one_chip, program, *SERVED[model],
+                                  served=True)
+    assert _weight_stack_converts(params, text) == []
+
+
+def test_float32_weights_are_converted_every_call(one_chip,
+                                                  minicpm_2_layers):
+    """The control: float32 stacks, as the engine holds them on a CPU,
+    are each converted to bfloat16 by the decode, ahead of its layer
+    loop (the cost the served weights take away)."""
+    params, text = _compiled_text(minicpm_2_layers, one_chip, "decode",
+                                  *SERVED["minicpm_2_layers"], served=False)
+    made = _weight_stack_converts(params, text)
+    assert sorted(dt for dt, _ in made) == ["bf16"] * 7, made
