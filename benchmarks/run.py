@@ -1,5 +1,5 @@
-"""Benchmark harness: one module per paper figure/table + the roofline
-report.  Prints ``name,us_per_call,derived`` CSV rows (harness contract).
+"""Benchmark harness: one module per paper figure/table.  Prints
+``name,us_per_call,derived`` CSV rows (harness contract).
 
 Per-figure argument parsing is defined ONCE: every suite declares its
 slow/fast kwargs in the ``_Suite`` table below, and the shared
@@ -36,8 +36,7 @@ def _suites(fast: bool) -> dict:
                             fig11_overhead, fig12_workflows,
                             fig13_autoscale, fig14_spot, fig15_rectify,
                             fig16_sharded, fig17_calibration,
-                            fig18_fairness, fig19_disagg, fig20_learned,
-                            roofline)
+                            fig18_fairness, fig19_disagg, fig20_learned)
 
     n_sim = 200 if fast else 400
     epochs = 12 if fast else 40
@@ -100,7 +99,6 @@ def _suites(fast: bool) -> dict:
         "fig20": _Suite(fig20_learned.run,
                         fast_kw=dict(n_seeds=2, fast=True),
                         seedable=True),
-        "roofline": _Suite(roofline.run),
     }
 
 

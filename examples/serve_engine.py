@@ -1,5 +1,5 @@
 """End-to-end serving driver (deliverable b): real JAX inference engines
-(the same model code the TPU dry-run lowers) serving batched requests
+(the same model code the benchmark serves on the TPU) serving batched requests
 behind an EMA-monitored proxy, including a token-ID migration of an
 in-flight request between engines — the paper's mechanism, live.
 

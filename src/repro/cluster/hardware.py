@@ -7,10 +7,7 @@ dominate reads), then rising once compute-bound — and its cross-GPU
 ordering (V100 > A40 > A800 > H800).
 
 The paper's four testbed GPUs are included for figure reproduction, plus
-TPU entries (the deployment target of this framework).  Per-arch serving
-rates for TPU slices can instead be derived from dry-run roofline terms
-(see benchmarks/roofline.py), which is how the large-scale simulation is
-wired to physics.
+TPU entries (the deployment target of this framework).
 """
 from __future__ import annotations
 

@@ -1,12 +1,10 @@
-"""Architecture registry: ``get_config("<arch-id>")`` and the assigned
-(architecture x shape) cell enumeration used by the dry-run and roofline."""
+"""Architecture registry: ``get_config("<arch-id>")``."""
 from __future__ import annotations
 
 import importlib
 
-from repro.configs.base import (SHAPES, BlockSpec, MLAConfig, ModelConfig,
-                                MoEConfig, ShapeSpec, SSMConfig, Stage,
-                                reduce_config)
+from repro.configs.base import (BlockSpec, MLAConfig, ModelConfig, MoEConfig,
+                                SSMConfig, Stage, reduce_config)
 
 # arch id -> module name
 _REGISTRY = {
@@ -25,17 +23,7 @@ _REGISTRY = {
     "qwen2.5-14b": "qwen25_14b",
 }
 
-ASSIGNED_ARCHS = tuple(list(_REGISTRY)[:10])
 ALL_ARCHS = tuple(_REGISTRY)
-
-# long_500k policy (see DESIGN.md §5): run only for archs with a
-# sub-quadratic / bounded-KV path.
-LONG_CONTEXT_ARCHS = frozenset({
-    "gemma3-27b", "gemma3-12b",            # 5:1 sliding:global
-    "jamba-v0.1-52b", "mamba2-1.3b",       # SSM / hybrid
-    "mixtral-8x22b",                       # sliding-window attention
-    "deepseek-v2-lite-16b",                # MLA compressed latent KV
-})
 
 
 def get_config(name: str) -> ModelConfig:
@@ -45,24 +33,7 @@ def get_config(name: str) -> ModelConfig:
     return mod.CONFIG
 
 
-def shape_applicable(arch: str, shape: str) -> bool:
-    """Whether an (arch x shape) cell is run (vs documented-skip)."""
-    if shape == "long_500k":
-        return arch in LONG_CONTEXT_ARCHS
-    return True
-
-
-def assigned_cells(include_skipped: bool = False):
-    """Yield (arch, shape_name) for the 10x4 assigned grid."""
-    for arch in ASSIGNED_ARCHS:
-        for shape in SHAPES:
-            if include_skipped or shape_applicable(arch, shape):
-                yield arch, shape
-
-
 __all__ = [
-    "ALL_ARCHS", "ASSIGNED_ARCHS", "LONG_CONTEXT_ARCHS", "SHAPES",
-    "BlockSpec", "MLAConfig", "ModelConfig", "MoEConfig", "ShapeSpec",
-    "SSMConfig", "Stage", "assigned_cells", "get_config", "reduce_config",
-    "shape_applicable",
+    "ALL_ARCHS", "BlockSpec", "MLAConfig", "ModelConfig", "MoEConfig",
+    "SSMConfig", "Stage", "get_config", "reduce_config",
 ]

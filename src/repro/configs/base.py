@@ -4,8 +4,7 @@ A ``ModelConfig`` fully describes a decoder-only LM backbone (dense, MoE,
 SSM, or hybrid) plus optional modality-stub frontends.  Layer stacks are
 expressed as *stages*: a stage is a repeating pattern of blocks that the
 model applies with ``jax.lax.scan`` over the repeat axis, keeping the HLO
-compact (pattern-sized, not depth-sized) so that 512-device dry-run
-compiles stay tractable.
+compact (pattern-sized, not depth-sized).
 """
 from __future__ import annotations
 
@@ -53,7 +52,6 @@ class MoEConfig:
     num_shared: int = 0           # always-on shared experts (DeepSeek style)
     d_ff_shared: int = 0          # hidden dim of the fused shared expert
     router_aux_weight: float = 0.01
-    capacity_factor: float = 2.0  # per-expert slots = ceil(T*k*cf/E)
     # the experts this chip holds, ids [0, experts_held), under expert
     # parallelism (None: all of them); the router keeps all num_experts
     experts_held: Optional[int] = None
@@ -162,7 +160,7 @@ class ModelConfig:
                 out.extend(st.pattern)
         return out
 
-    # -- parameter counting (analytic; used for roofline MODEL_FLOPS) ------
+    # -- parameter counting (analytic; the simulator's model footprint) ----
 
     def param_count(self, *, active_only: bool = False) -> int:
         d = self.d_model
@@ -236,26 +234,6 @@ def local_global_stages(num_layers: int, local_per_global: int,
     return tuple(stages)
 
 
-# ---------------------------------------------------------------------------
-# Input shapes assigned to every LM arch
-# ---------------------------------------------------------------------------
-
-@dataclasses.dataclass(frozen=True)
-class ShapeSpec:
-    name: str
-    kind: str           # train | prefill | decode
-    seq_len: int
-    global_batch: int
-
-
-SHAPES = {
-    "train_4k": ShapeSpec("train_4k", "train", 4096, 256),
-    "prefill_32k": ShapeSpec("prefill_32k", "prefill", 32768, 32),
-    "decode_32k": ShapeSpec("decode_32k", "decode", 32768, 128),
-    "long_500k": ShapeSpec("long_500k", "decode", 524288, 1),
-}
-
-
 def reduce_config(cfg: ModelConfig, *, layers_per_stage: int = 1,
                   d_model: int = 64, d_ff: int = 128, vocab: int = 256,
                   num_experts: Optional[int] = None) -> ModelConfig:
@@ -281,7 +259,6 @@ def reduce_config(cfg: ModelConfig, *, layers_per_stage: int = 1,
                         d_ff_expert=d_ff // 2,
                         num_shared=min(cfg.moe.num_shared, 1),
                         d_ff_shared=d_ff // 2 if cfg.moe.num_shared else 0,
-                        capacity_factor=float(ne),  # no drops in smoke tests
                         norm_topk_prob=cfg.moe.norm_topk_prob)
     mla = None
     if cfg.mla is not None:

@@ -1,9 +1,9 @@
 """Per-instance serving engine: continuous batching over the JAX model.
 
 This is the functional engine the proxy routes to — it runs real prefill
-and decode steps (the same ``repro.models`` code the dry-run lowers for
-TPU), manages request lifecycles, reports the black-box timing events the
-EMA estimator consumes, and supports token-ID checkpointing of in-flight
+and decode steps of the ``repro.models`` code, manages request
+lifecycles, reports the black-box timing events the EMA estimator
+consumes, and supports token-ID checkpointing of in-flight
 requests (the migration/fault-tolerance path).  Each engine lives on one
 device, which holds its params and cache (one engine per chip): on CPU it
 serves reduced configs, on a TPU chip published widths with the depth
@@ -50,7 +50,6 @@ from jax.profiler import TraceAnnotation
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs.base import ModelConfig
-from repro.distributed.context import NULL_CTX, ShardCtx
 from repro.models import (decode_step, init_cache, init_params, prefill,
                           prefill_chunk, ring_convert_cache, serving_params)
 
@@ -84,8 +83,8 @@ class EngineRequest:
 
 
 class InferenceEngine:
-    """Static-batch continuous decoding engine (batch slots + shared
-    dense cache; the paged Pallas kernel is the TPU fast path).
+    """Static-batch continuous decoding engine: batch slots and one dense
+    cache, which decode reads in full under a mask.
 
     ``device`` (default ``jax.devices()[0]``) holds the params and the
     cache; ``params=None`` initialises them there from ``seed``, so
@@ -94,14 +93,12 @@ class InferenceEngine:
     tree it was handed; ``weight_bytes`` says what it holds."""
 
     def __init__(self, cfg: ModelConfig, params=None, *, max_batch: int = 8,
-                 max_len: int = 256, ctx: ShardCtx = NULL_CTX, seed: int = 0,
-                 greedy: bool = True, prefill_chunk: Optional[int] = None,
+                 max_len: int = 256, seed: int = 0,
+                 prefill_chunk: Optional[int] = None,
                  max_events: int = 4096, device=None):
         self.cfg = cfg
-        self.ctx = ctx
         self.max_batch = max_batch
         self.max_len = max_len
-        self.greedy = greedy
         self.device = device if device is not None else jax.devices()[0]
         self._dev = self.device.id          # the spans' ``dev`` argument
         on_device = SingleDeviceSharding(self.device)
@@ -133,17 +130,16 @@ class InferenceEngine:
             out_shardings=on_device)
         self.slots: List[Optional[EngineRequest]] = [None] * max_batch
         self.queue: List[EngineRequest] = []
-        self._decode = functools.partial(DECODE, cfg=cfg, ctx=ctx)
+        self._decode = functools.partial(DECODE, cfg=cfg)
         # one compile per prompt length
-        self._prefill = functools.partial(PREFILL, cfg=cfg, max_len=max_len,
-                                          ctx=ctx)
+        self._prefill = functools.partial(PREFILL, cfg=cfg, max_len=max_len)
         self._splice = jax.jit(splice_cache, donate_argnums=0)
         # chunked prefill: only full/window mixers are chunk-resumable
         chunkable = all(blk.mixer in ("full", "window")
                         for blk in cfg.layer_list())
         self.prefill_chunk = (prefill_chunk
                               if (prefill_chunk and chunkable) else None)
-        self._chunk_fn = functools.partial(PREFILL_CHUNK, cfg=cfg, ctx=ctx)
+        self._chunk_fn = functools.partial(PREFILL_CHUNK, cfg=cfg)
         # one request staged at a time: (slot, req, linear cache, t0,
         # tokens consumed, last-chunk logits)
         self._staging: Optional[dict] = None
@@ -331,24 +327,22 @@ class InferenceEngine:
         return self.completed
 
 
-def engine_decode(params, cache, tokens, *, cfg: ModelConfig,
-                  ctx: ShardCtx = NULL_CTX):
+def engine_decode(params, cache, tokens, *, cfg: ModelConfig):
     """One decode iteration plus its greedy pick: (logits [B, V], out,
     new cache).  ``out`` is what the host reads: the next tokens [B]; for
     a model with MoE layers [2, B], the next tokens over each slot's held
     routes (``decode_step``), so that both cross in one transfer."""
-    logits, cache, held = decode_step(params, cfg, cache, tokens, ctx=ctx)
+    logits, cache, held = decode_step(params, cfg, cache, tokens)
     out = jnp.argmax(logits, axis=-1)
     if held is not None:
         out = jnp.stack([out, held])
     return logits, out, cache
 
 
-def engine_prefill(params, tokens, *, cfg: ModelConfig, max_len: int,
-                   ctx: ShardCtx = NULL_CTX):
+def engine_prefill(params, tokens, *, cfg: ModelConfig, max_len: int):
     """Prompt prefill plus its greedy pick: (logits [B, V], next tokens
     [B], ring-layout cache sized ``max_len``)."""
-    logits, cache = prefill(params, cfg, tokens, max_len=max_len, ctx=ctx)
+    logits, cache = prefill(params, cfg, tokens, max_len=max_len)
     return logits, jnp.argmax(logits, axis=-1), cache
 
 
@@ -366,18 +360,16 @@ def splice_cache(cache, one, slot, pos):
 
 
 def engine_prefill_chunk(params, cache, tokens, n_valid, *,
-                         cfg: ModelConfig, ctx: ShardCtx = NULL_CTX):
+                         cfg: ModelConfig):
     """One staged chunk of a chunked prefill."""
-    return prefill_chunk(params, cfg, cache, tokens, n_valid=n_valid,
-                         ctx=ctx)
+    return prefill_chunk(params, cfg, cache, tokens, n_valid=n_valid)
 
 
 # The engine's programs: module-level functions jitted with the model
 # configuration static, so that the trace names them after the function.
 # The decode donates the cache, so the old and the new cache never
 # coexist on the device.
-DECODE = jax.jit(engine_decode, static_argnames=("cfg", "ctx"),
-                 donate_argnums=1)
-PREFILL = jax.jit(engine_prefill, static_argnames=("cfg", "max_len", "ctx"))
-PREFILL_CHUNK = jax.jit(engine_prefill_chunk, static_argnames=("cfg", "ctx"))
+DECODE = jax.jit(engine_decode, static_argnames="cfg", donate_argnums=1)
+PREFILL = jax.jit(engine_prefill, static_argnames=("cfg", "max_len"))
+PREFILL_CHUNK = jax.jit(engine_prefill_chunk, static_argnames="cfg")
 

@@ -5,7 +5,7 @@ checkpointing with automatic resume.
 CPU example (minutes):
   PYTHONPATH=src python -m repro.launch.train --arch llama3.1-8b --reduced \
       --steps 100 --batch 8 --seq 64 --ckpt-dir /tmp/ckpt
-On a TPU mesh the same entry point shards via the production specs.
+It trains on one device.
 """
 from __future__ import annotations
 
@@ -18,7 +18,6 @@ import numpy as np
 
 from repro.configs import get_config, reduce_config
 from repro.data.lm_data import SyntheticLM
-from repro.distributed.context import NULL_CTX
 from repro.models import init_params
 from repro.training.checkpoint import (AsyncCheckpointer, latest_step,
                                        restore_checkpoint)
@@ -50,7 +49,7 @@ def main():
                           total_steps=args.steps,
                           schedule=("wsd" if cfg.lr_schedule == "wsd"
                                     else "cosine"))
-    step_fn = jax.jit(make_train_step(cfg, opt_cfg, NULL_CTX, ce_chunk=64))
+    step_fn = jax.jit(make_train_step(cfg, opt_cfg, ce_chunk=64))
     data = SyntheticLM(cfg.vocab_size, seed=0)
 
     start = 0

@@ -15,10 +15,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import MLAConfig, ModelConfig, MoEConfig, YaRN
-from repro.distributed.context import NULL_CTX, ShardCtx
 from repro.models.grouped_gemm import grouped_gemm
 
 # ---------------------------------------------------------------------------
@@ -112,8 +110,7 @@ def init_attention(key, cfg: ModelConfig, dtype):
     return p
 
 
-def _qkv(p, cfg: ModelConfig, x, positions, ctx: ShardCtx,
-         decode: bool = False):
+def _qkv(p, cfg: ModelConfig, x, positions):
     B, L, _ = x.shape
     H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     q = (x @ p["wq"]).reshape(B, L, H, hd)
@@ -124,34 +121,6 @@ def _qkv(p, cfg: ModelConfig, x, positions, ctx: ShardCtx,
         k = rms_norm(k, p["k_norm"], cfg.norm_eps)
     q = rope(q, positions, cfg.rope_theta)
     k = rope(k, positions, cfg.rope_theta)
-    bspec = ctx.batch_spec_entry(B)
-    kv_tp = ctx.enabled and KV % ctx.tp_size == 0
-    if decode:
-        # flash-decoding regime when KV heads don't divide the model axis:
-        # replicate the one-token q/k/v, shard the cache on sequence, and
-        # let the partial-softmax combine run as tiny all-reduces.
-        if kv_tp:
-            q = ctx.constraint(q, bspec, None, ctx.model_axis, None)
-        else:
-            q = ctx.constraint(q, bspec, None, None, None)
-        return q, k, v
-    if ctx.enabled:
-        if H % ctx.tp_size == 0:
-            q = ctx.constraint(q, bspec, None, ctx.model_axis, None)
-        else:
-            # H doesn't divide TP: head_dim-sharded q would force an
-            # all-reduce of the full [*, Lq, Lk] score tensor per chunk
-            # (observed 19.8 TB/step for musicgen prefill_32k).  Instead
-            # replicate q and shard K/V on *sequence*: scores stay local
-            # and only the softmax max/sum + output partials reduce.
-            q = ctx.constraint(q, bspec, None, None, None)
-        if kv_tp:
-            k = ctx.constraint(k, bspec, None, ctx.model_axis, None)
-            v = ctx.constraint(v, bspec, None, ctx.model_axis, None)
-        else:
-            seq = ctx.model_axis if L % ctx.tp_size == 0 else None
-            k = ctx.constraint(k, bspec, seq, None, None)
-            v = ctx.constraint(v, bspec, seq, None, None)
     return q, k, v
 
 
@@ -212,19 +181,18 @@ def causal_mask(Lq: int, Lk: int, window: Optional[int] = None,
     return m[None]
 
 
-def attn_forward(p, cfg: ModelConfig, x, positions, window: Optional[int],
-                 ctx: ShardCtx = NULL_CTX):
+def attn_forward(p, cfg: ModelConfig, x, positions, window: Optional[int]):
     """Full-sequence causal attention (train / prefill).
 
     Returns (y, (k, v)) so prefill can populate the cache."""
     h = rms_norm(x, p["ln"], cfg.norm_eps)
-    q, k, v = _qkv(p, cfg, h, positions, ctx)
+    q, k, v = _qkv(p, cfg, h, positions)
     y = _sdpa_chunked(q, k, v, window, cfg.logit_softcap)
     return y @ p["wo"], (k, v)
 
 
 def attn_decode(p, cfg: ModelConfig, x, cache_k, cache_v, pos,
-                window: Optional[int], ctx: ShardCtx = NULL_CTX):
+                window: Optional[int]):
     """One-token decode.  x: [B,1,d]; cache_k/v: [B,S,KV,hd]; pos: [B].
 
     For window layers the cache is a ring buffer of size min(S, window)
@@ -232,20 +200,15 @@ def attn_decode(p, cfg: ModelConfig, x, cache_k, cache_v, pos,
     irrelevant to scores.
 
     Attention reads the cache with the new token selected in at its slot
-    (``select_token``, which fuses into the score and value reads).
-    Returns (y, (k_new, v_new)), the token's rows [B,1,KV,hd], which
-    ``model.decode_step`` stores in one of two ways:
-
-    - under a mesh (``ctx.enabled``: the sharded dry-run, whose cache may
-      be sharded on S), ``select_token`` over the whole layer slice, which
-      is written back: elementwise along S, so GSPMD keeps it local;
-    - with no mesh (every served engine), ``write_token_rows`` once per
-      stage after all its layers have read the cache: B row writes in
-      place, instead of a rewrite of the whole cache every layer.
+    (``select_token``, which fuses into the score and value reads); the
+    cache itself is not written here.  Returns (y, (k_new, v_new)), the
+    token's rows [B,1,KV,hd], which ``model.decode_step`` writes with
+    ``write_token_rows`` once per stage, after all its layers have read
+    the cache: B row writes in place, not a rewrite of the whole cache.
     """
     S = cache_k.shape[1]
     h = rms_norm(x, p["ln"], cfg.norm_eps)
-    q, k_new, v_new = _qkv(p, cfg, h, pos[:, None], ctx, decode=True)
+    q, k_new, v_new = _qkv(p, cfg, h, pos[:, None])
     # valid slots: ring full once pos >= S-1, else slots <= pos
     valid = (jnp.arange(S)[None, :] <= pos[:, None]) | (pos[:, None] >= S)
     y = _sdpa(q, select_token(cache_k, k_new, pos),
@@ -256,9 +219,8 @@ def attn_decode(p, cfg: ModelConfig, x, cache_k, cache_v, pos,
 
 def select_token(cache, new, pos):
     """``cache`` [B,S,...] with row b's slot ``pos[b] % S`` replaced by
-    ``new`` [B,1,...]: a masked select over the whole cache.  It is
-    elementwise along a (possibly sequence-sharded) S, so GSPMD never falls
-    back to its "involuntary full rematerialization" replication path."""
+    ``new`` [B,1,...]: a masked select, which the attention reads fuse,
+    so the selected cache is never materialized."""
     S = cache.shape[1]
     hit = (jnp.arange(S)[None, :] == (pos % S)[:, None])[
         (...,) + (None,) * (cache.ndim - 2)]
@@ -281,7 +243,7 @@ def write_token_rows(cache, new, pos):
 
 
 def attn_chunk(p, cfg: ModelConfig, x, cache_k, cache_v, pos0,
-               window: Optional[int], ctx: ShardCtx = NULL_CTX):
+               window: Optional[int]):
     """Chunked prefill: extend a LINEAR (slot == position) KV cache by C
     prompt tokens starting at ``pos0``.  x: [B,C,d]; cache_k/v:
     [B,S,KV,hd] with S >= pos0 + C; pos0: [B].
@@ -295,7 +257,7 @@ def attn_chunk(p, cfg: ModelConfig, x, cache_k, cache_v, pos0,
     S = cache_k.shape[1]
     h = rms_norm(x, p["ln"], cfg.norm_eps)
     positions = pos0[:, None] + jnp.arange(C)[None, :]
-    q, k_new, v_new = _qkv(p, cfg, h, positions, ctx)
+    q, k_new, v_new = _qkv(p, cfg, h, positions)
 
     def put(ck, kn, p0):
         return lax.dynamic_update_slice(ck, kn, (p0, 0, 0))
@@ -357,8 +319,7 @@ def _mla_q_and_latent(p, cfg: ModelConfig, h, positions):
 
 
 @jax.named_scope("mla")
-def mla_forward(p, cfg: ModelConfig, x, positions, ctx: ShardCtx = NULL_CTX,
-                chunk: int = 512):
+def mla_forward(p, cfg: ModelConfig, x, positions, chunk: int = 512):
     """Full-sequence MLA (expanded form), scanned over query chunks so peak
     score memory is [B, H, chunk, L]; a ragged tail pads the queries only.
     Returns (y, (c_kv, k_rope))."""
@@ -370,12 +331,6 @@ def mla_forward(p, cfg: ModelConfig, x, positions, ctx: ShardCtx = NULL_CTX,
     k_nope = (c_kv @ p["w_uk"]).reshape(B, L, H, m.nope_head_dim)
     v = (c_kv @ p["w_uv"]).reshape(B, L, H, m.v_head_dim)
     scale = mla_softmax_scale(m)
-    bspec = ctx.batch_spec_entry(B)
-    hspec = ctx.heads_spec(H, m.nope_head_dim)
-    if hspec is not None:
-        q_nope = ctx.constraint(q_nope, bspec, None, *hspec)
-        k_nope = ctx.constraint(k_nope, bspec, None, *hspec)
-        v = ctx.constraint(v, bspec, None, *hspec)
 
     def attend(qn, qr, mask):
         scores = (jnp.einsum("bqhd,bshd->bhqs", qn, k_nope)
@@ -411,8 +366,7 @@ def mla_forward(p, cfg: ModelConfig, x, positions, ctx: ShardCtx = NULL_CTX,
 
 
 @jax.named_scope("mla")
-def mla_decode(p, cfg: ModelConfig, x, cache_ckv, cache_krope, pos,
-               ctx: ShardCtx = NULL_CTX):
+def mla_decode(p, cfg: ModelConfig, x, cache_ckv, cache_krope, pos):
     """One-token MLA decode with matrix absorption: scores and values are
     computed directly in the compressed latent space, so per-step cost is
     O(L * (kv_lora + rope_dim)) instead of O(L * H * head_dim).
@@ -421,7 +375,7 @@ def mla_decode(p, cfg: ModelConfig, x, cache_ckv, cache_krope, pos,
     Attention reads the cache with the new token selected in at its slot
     (``select_token``).  Returns (y, (c_new [B,1,kv_lora], kr_new
     [B,1,rope_dim])), the token's latent rows, which ``model.decode_step``
-    stores as it stores ``attn_decode``'s.
+    writes as it writes ``attn_decode``'s.
     """
     m: MLAConfig = cfg.mla
     S = cache_ckv.shape[1]
@@ -462,12 +416,10 @@ def init_ffn(key, cfg: ModelConfig, dtype, d_ff: Optional[int] = None):
     }
 
 
-def ffn_forward(p, cfg: ModelConfig, x, ctx: ShardCtx = NULL_CTX):
+def ffn_forward(p, cfg: ModelConfig, x):
     h = rms_norm(x, p["ln"], cfg.norm_eps)
     act = _act(cfg.act)
     z = act(h @ p["w_gate"]) * (h @ p["w_up"])
-    z = ctx.constraint(z, ctx.batch_spec_entry(x.shape[0]), None,
-                       ctx.model_axis_if_divides(z.shape[-1]))
     return z @ p["w_down"]
 
 
@@ -495,10 +447,6 @@ def init_moe(key, cfg: ModelConfig, dtype):
     return p
 
 
-def moe_capacity(T: int, K: int, E: int, cf: float) -> int:
-    return int(min(T * K, max(-(-T * K * cf // E), 16)))
-
-
 def _route(p, mo: MoEConfig, xt):
     """Softmax over every expert's logit, greedy top-k: (probs [T, E],
     weights [T, K], expert ids [T, K]); the weights renormalised to sum
@@ -520,66 +468,6 @@ def _aux_loss(probs, top_i, E: int):
                            axis=(0, 1))
     frac_probs = jnp.mean(probs, axis=0)
     return E * jnp.sum(frac_tokens * frac_probs)
-
-
-def _moe_local(p, cfg: ModelConfig, xt, act, axis_name: Optional[str] = None):
-    """Sort + capacity-dispatch MoE over local tokens xt [T, d], every
-    expert held.
-
-    Tokens are sorted by expert and scattered into [E, C, d] slots
-    (C = capacity per expert); expert GEMMs are batched einsums.  This is
-    the GShard/MaxText formulation: peak memory is O(T*K*cf*d) and the
-    XLA graph contains no data-dependent dense expansions — unlike
-    ``lax.ragged_dot`` on a backend without a grouped-matmul kernel, whose
-    one-hot decomposition materializes [E, T*K, d] (observed 640 GB/device
-    on deepseek-v2-lite train_4k).
-    Tokens beyond capacity are dropped (standard; the aux loss keeps
-    routing balanced so drops are rare at cf=2).
-
-    Returns (y [T,d], aux_loss).  When ``axis_name`` is given the expert
-    ff dims are sharded across it and the down-projection is psummed
-    (tensor parallel inside shard_map).
-    """
-    mo: MoEConfig = cfg.moe
-    T, d = xt.shape
-    E, K = mo.num_experts, mo.top_k
-    assert mo.held == E, "the capacity dispatch computes every expert"
-    C = moe_capacity(T, K, E, mo.capacity_factor)
-    probs, top_p, top_i = _route(p, mo, xt)
-
-    flat_e = top_i.reshape(-1)                                 # [M = T*K]
-    M = T * K
-    order = jnp.argsort(flat_e)
-    e_sorted = jnp.take(flat_e, order)
-    token_of = order // K
-    group_sizes = jnp.bincount(flat_e, length=E).astype(jnp.int32)
-    starts = jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                              jnp.cumsum(group_sizes)[:-1]])
-    # gather-based dispatch: slot s of expert e reads sorted row
-    # starts[e] + s (scatter-free — XLA's bf16->f32 scatter normalization
-    # would otherwise materialize fp32 [E,C,d] buffers)
-    slot_rows = starts[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
-    slot_valid = jnp.arange(C, dtype=jnp.int32)[None, :] < group_sizes[:, None]
-    slot_rows = jnp.minimum(slot_rows, M - 1)
-    xs = jnp.take(xt, token_of, axis=0)                        # [M, d]
-    slots = jnp.take(xs, slot_rows.reshape(-1), axis=0) \
-        .reshape(E, C, d) * slot_valid[..., None].astype(xt.dtype)
-    gate = jnp.einsum("ecd,edf->ecf", slots, p["w_gate"])
-    up = jnp.einsum("ecd,edf->ecf", slots, p["w_up"])
-    hidden = act(gate) * up
-    out_slots = jnp.einsum("ecf,efd->ecd", hidden, p["w_down"])
-    if axis_name is not None:
-        out_slots = lax.psum(out_slots, axis_name)
-    # combine: inverse-permutation gather back to [T, K, d], weighted sum
-    pos = jnp.arange(M, dtype=jnp.int32) - jnp.take(starts, e_sorted)
-    keep = pos < C
-    pos_c = jnp.where(keep, pos, C - 1)
-    out_sorted = out_slots[e_sorted, pos_c] \
-        * keep[:, None].astype(out_slots.dtype)                # [M, d]
-    inv_order = jnp.argsort(order)
-    out_tk = jnp.take(out_sorted, inv_order, axis=0).reshape(T, K, d)
-    y = jnp.einsum("tkd,tk->td", out_tk, top_p.astype(out_tk.dtype))
-    return y, _aux_loss(probs, top_i, E)
 
 
 def _moe_held(p, cfg: ModelConfig, xt, act, one_token: bool):
@@ -631,69 +519,20 @@ def _moe_held(p, cfg: ModelConfig, xt, act, one_token: bool):
 
 
 @jax.named_scope("moe")
-def moe_forward(p, cfg: ModelConfig, x, ctx: ShardCtx = NULL_CTX):
+def moe_forward(p, cfg: ModelConfig, x):
     """x: [B, L, d] -> (y, aux_loss, held [B, L] int32).
 
-    ``held`` counts each token's top-k picks that fell on an expert this
-    chip holds.  With no mesh (every served engine) the held experts'
-    part is computed with no token dropped (``_moe_held``), plus the
-    shared experts for every token.  Under a mesh every expert is held
-    and the capacity dispatch runs: under shard_map, tokens stay local to
-    their (pod, data) shard (routing is per-token), expert ff dims are
-    TP-sharded over the model axis, and only the O(T_local x d)
-    down-projection psum crosses model-axis links.
+    The held experts' part is computed with no token dropped
+    (``_moe_held``), plus the shared experts for every token.  ``held``
+    counts each token's top-k picks that fell on an expert this chip
+    holds.
     """
     B, L, d = x.shape
     act = _act(cfg.act)
-    mo: MoEConfig = cfg.moe
-    use_sm = (ctx.enabled and ctx.use_shard_map_moe
-              and B % ctx.dp_size == 0
-              and mo.d_ff_expert % ctx.tp_size == 0)
-    if use_sm:
-        bspec = ctx.data_axes if len(ctx.data_axes) > 1 else ctx.data_axes[0]
-        w_specs = {
-            "ln": P(None),
-            "router": P(None, None),
-            "w_gate": P(None, None, ctx.model_axis),
-            "w_up": P(None, None, ctx.model_axis),
-            "w_down": P(None, ctx.model_axis, None),
-        }
-        if "shared" in p:
-            w_specs["shared"] = {
-                "w_gate": P(None, ctx.model_axis),
-                "w_up": P(None, ctx.model_axis),
-                "w_down": P(ctx.model_axis, None),
-            }
-
-        def body(xt, pp):
-            xt2 = xt.reshape(-1, d)
-            y, aux = _moe_local(pp, cfg, xt2, act, axis_name=ctx.model_axis)
-            if "shared" in pp:
-                sp = pp["shared"]
-                zs = act(xt2 @ sp["w_gate"]) * (xt2 @ sp["w_up"])
-                y = y + lax.psum(zs @ sp["w_down"], ctx.model_axis)
-            aux = lax.pmean(aux, ctx.data_axes)
-            return y.reshape(xt.shape), aux
-
-        h = rms_norm(x, p["ln"], cfg.norm_eps)
-        h = ctx.constraint(h, bspec, None, None)
-        pp = {k: v for k, v in p.items() if k != "ln"}
-        y, aux = jax.shard_map(
-            body, mesh=ctx.mesh,
-            in_specs=(P(bspec, None, None),
-                      {k: w_specs[k] for k in pp}),
-            out_specs=(P(bspec, None, None), P()),
-            check_vma=False,
-        )(h, pp)
-        return y, aux, jnp.full((B, L), mo.top_k, jnp.int32)
     h = rms_norm(x, p["ln"], cfg.norm_eps)
-    if ctx.enabled:
-        y, aux = _moe_local(p, cfg, h.reshape(-1, d), act)
-        held = jnp.full((B, L), mo.top_k, jnp.int32)
-    else:
-        y, aux, held = _moe_held(p, cfg, h.reshape(-1, d), act,
-                                 one_token=L == 1)
-        held = held.reshape(B, L)
+    y, aux, held = _moe_held(p, cfg, h.reshape(-1, d), act,
+                             one_token=L == 1)
+    held = held.reshape(B, L)
     y = y.reshape(B, L, d)
     if "shared" in p:
         sp = p["shared"]

@@ -2,10 +2,9 @@
 
 Layer stacks run as ``lax.scan`` over each stage's repeat axis (stage
 pattern unrolled inside the body), so the lowered HLO is pattern-sized
-rather than depth-sized — this is what keeps 512-device dry-run compiles
-of 27B-62L models tractable.  Caches are pytrees whose structure mirrors
-``params["stages"]`` with a leading repeat axis, letting decode scan over
-(params, cache) jointly and emit the updated cache as scan outputs.
+rather than depth-sized, which keeps compiles of deep models short.
+Caches are pytrees whose structure mirrors ``params["stages"]`` with a
+leading repeat axis, letting decode scan over (params, cache) jointly.
 """
 from __future__ import annotations
 
@@ -17,7 +16,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.configs.base import BlockSpec, ModelConfig, Stage
-from repro.distributed.context import NULL_CTX, ShardCtx
 from repro.models import layers as L
 from repro.models import ssd
 
@@ -43,9 +41,9 @@ def _init_block(key, cfg: ModelConfig, blk: BlockSpec, dtype):
 
 
 def padded_vocab(cfg: ModelConfig, multiple: int = 256) -> int:
-    """Pad the embedding table so the vocab dim shards over the model axis
-    (odd released sizes like 151655 / 122753 otherwise force replicated
-    fp32 logits).  Padded ids never appear in data; their logits join the
+    """Pad the embedding table to a multiple of ``multiple`` rows, so the
+    logits of odd released sizes like 151655 / 122753 fill whole TPU
+    tiles.  Padded ids never appear in data; their logits join the
     softmax like any other never-sampled token (MaxText-style)."""
     return -(-cfg.vocab_size // multiple) * multiple
 
@@ -109,40 +107,36 @@ def serving_params(params):
 # ---------------------------------------------------------------------------
 
 def _apply_block(blk: BlockSpec, p, cfg: ModelConfig, x, positions,
-                 ctx: ShardCtx, collect_cache: bool, max_len: int):
+                 collect_cache: bool, max_len: int):
     """One block, full sequence.  Returns (x, aux, cache_entry|None)."""
     aux = jnp.float32(0.0)
     entry = None
-    B, Ltot, _ = x.shape
     if blk.mixer in ("full", "window"):
-        y, (k, v) = L.attn_forward(p["attn"], cfg, x, positions, blk.window,
-                                   ctx)
+        y, (k, v) = L.attn_forward(p["attn"], cfg, x, positions, blk.window)
         x = x + y
         if collect_cache:
             S = min(blk.window, max_len) if blk.window else max_len
             k_c, v_c = _to_ring(k, S), _to_ring(v, S)
             entry = {"k": k_c, "v": v_c}
     elif blk.mixer == "mla":
-        y, (ckv, kr) = L.mla_forward(p["attn"], cfg, x, positions, ctx)
+        y, (ckv, kr) = L.mla_forward(p["attn"], cfg, x, positions)
         x = x + y
         if collect_cache:
             entry = {"ckv": _to_ring(ckv, max_len), "kr": _to_ring(kr, max_len)}
     elif blk.mixer == "mamba":
         if collect_cache:
             y, (conv_tail, state) = ssd.mamba_forward(
-                p["mixer"], cfg, x, ctx, return_state=True)
+                p["mixer"], cfg, x, return_state=True)
             entry = {"conv": conv_tail, "ssm": state}
         else:
-            y = ssd.mamba_forward(p["mixer"], cfg, x, ctx)
+            y = ssd.mamba_forward(p["mixer"], cfg, x)
         x = x + y
     if blk.ffn == "dense":
-        x = x + L.ffn_forward(p["ffn"], cfg, x, ctx)
+        x = x + L.ffn_forward(p["ffn"], cfg, x)
     elif blk.ffn == "moe":
-        y, a, _ = L.moe_forward(p["ffn"], cfg, x, ctx)
+        y, a, _ = L.moe_forward(p["ffn"], cfg, x)
         x = x + y
         aux = aux + a
-    bspec = ctx.batch_spec_entry(B)
-    x = ctx.constraint(x, bspec, ctx.seq_entry(Ltot), None)
     return x, aux, entry
 
 
@@ -157,8 +151,8 @@ def _to_ring(k, S: int):
     return buf.at[:, slots].set(tail)
 
 
-def _run_stages(params, cfg: ModelConfig, x, positions, ctx: ShardCtx,
-                remat: bool, collect_cache: bool, max_len: int):
+def _run_stages(params, cfg: ModelConfig, x, positions, remat: bool,
+                collect_cache: bool, max_len: int):
     aux_total = jnp.float32(0.0)
     caches = []
     for si, stage in enumerate(cfg.stages):
@@ -177,10 +171,10 @@ def _run_stages(params, cfg: ModelConfig, x, positions, ctx: ShardCtx,
                     # live at once
                     apply = jax.checkpoint(
                         _apply_block,
-                        static_argnums=(0, 2, 5, 6, 7),  # blk/cfg/ctx/flags
+                        static_argnums=(0, 2, 5, 6),  # blk/cfg/flags
                         prevent_cse=False)
                 xx, a, entry = apply(
-                    blk, layer_p[f"blk{pi}"], cfg, xx, positions, ctx,
+                    blk, layer_p[f"blk{pi}"], cfg, xx, positions,
                     collect_cache, max_len)
                 aux = aux + a
                 if entry is not None:
@@ -198,23 +192,21 @@ def _run_stages(params, cfg: ModelConfig, x, positions, ctx: ShardCtx,
 # Public entry points
 # ---------------------------------------------------------------------------
 
-def embed_tokens(params, cfg: ModelConfig, tokens, prefix_embeds,
-                 ctx: ShardCtx):
+def embed_tokens(params, cfg: ModelConfig, tokens, prefix_embeds):
     x = jnp.take(params["embed"], tokens, axis=0)
     if cfg.n_prefix_embeds and prefix_embeds is not None:
         pre = prefix_embeds.astype(x.dtype) @ params["prefix_proj"]
         x = jnp.concatenate([pre, x], axis=1)
-    bspec = ctx.batch_spec_entry(x.shape[0])
-    return ctx.constraint(x, bspec, ctx.seq_entry(x.shape[1]), None)
+    return x
 
 
 def model_forward(params, cfg: ModelConfig, tokens, prefix_embeds=None,
-                  ctx: ShardCtx = NULL_CTX, remat: bool = True):
+                  remat: bool = True):
     """Teacher-forcing forward.  Returns (final_hidden [B,S,d], aux_loss)."""
-    x = embed_tokens(params, cfg, tokens, prefix_embeds, ctx)
+    x = embed_tokens(params, cfg, tokens, prefix_embeds)
     B, S, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
-    x, aux, _ = _run_stages(params, cfg, x, positions, ctx, remat,
+    x, aux, _ = _run_stages(params, cfg, x, positions, remat,
                             collect_cache=False, max_len=S)
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     return x, aux
@@ -232,17 +224,16 @@ def logits_fn(params, cfg: ModelConfig, hidden):
 
 
 def prefill(params, cfg: ModelConfig, tokens, max_len: int,
-            prefix_embeds=None, ctx: ShardCtx = NULL_CTX,
-            remat: bool = False):
+            prefix_embeds=None, remat: bool = False):
     """Process a prompt, build the KV/state cache sized ``max_len``.
 
     Returns (last_token_logits [B, V], cache).  ``cache["pos"]`` holds the
     per-request next position.
     """
-    x = embed_tokens(params, cfg, tokens, prefix_embeds, ctx)
+    x = embed_tokens(params, cfg, tokens, prefix_embeds)
     B, S, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
-    x, _, caches = _run_stages(params, cfg, x, positions, ctx, remat,
+    x, _, caches = _run_stages(params, cfg, x, positions, remat,
                                collect_cache=True, max_len=max_len)
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = logits_fn(params, cfg, x[:, -1])
@@ -252,7 +243,7 @@ def prefill(params, cfg: ModelConfig, tokens, max_len: int,
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int,
                dtype=jnp.bfloat16, ring: bool = True):
-    """Empty cache (decode-from-scratch or dry-run ShapeDtypeStruct base).
+    """Empty cache: the engine's decode batch and staging buffers.
 
     ``ring=False`` sizes every attention buffer ``max_len`` with slot ==
     absolute position (no wrap): the staging layout ``prefill_chunk``
@@ -294,8 +285,7 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
     return {"stages": stages, "pos": jnp.zeros((batch,), jnp.int32)}
 
 
-def prefill_chunk(params, cfg: ModelConfig, cache, tokens, n_valid=None,
-                  ctx: ShardCtx = NULL_CTX):
+def prefill_chunk(params, cfg: ModelConfig, cache, tokens, n_valid=None):
     """Extend a LINEAR cache (``init_cache(..., ring=False)``) by one
     prompt chunk — the engine's bounded-prefill-budget iteration, so a
     long prompt is admitted as several cheap steps interleaved with
@@ -322,8 +312,6 @@ def prefill_chunk(params, cfg: ModelConfig, cache, tokens, n_valid=None,
     if n_valid is None:
         n_valid = jnp.full((B,), C, jnp.int32)
     x = jnp.take(params["embed"], tokens, axis=0)
-    bspec = ctx.batch_spec_entry(B)
-    x = ctx.constraint(x, bspec, ctx.seq_entry(C), None)
 
     new_stage_caches = []
     for si, stage in enumerate(cfg.stages):
@@ -344,15 +332,14 @@ def prefill_chunk(params, cfg: ModelConfig, cache, tokens, n_valid=None,
                 c_ = layer_c[f"blk{pi}"]
                 y, (ck, cv) = L.attn_chunk(
                     p_["attn"], cfg, xx, c_["k"], c_["v"], pos0,
-                    blk.window, ctx)
+                    blk.window)
                 xx = xx + y
                 new_c[f"blk{pi}"] = {"k": ck, "v": cv}
                 if blk.ffn == "dense":
-                    xx = xx + L.ffn_forward(p_["ffn"], cfg, xx, ctx)
+                    xx = xx + L.ffn_forward(p_["ffn"], cfg, xx)
                 elif blk.ffn == "moe":
-                    y2, _, _ = L.moe_forward(p_["ffn"], cfg, xx, ctx)
+                    y2, _, _ = L.moe_forward(p_["ffn"], cfg, xx)
                     xx = xx + y2
-            xx = ctx.constraint(xx, bspec, ctx.seq_entry(C), None)
             cache_full = jax.tree.map(
                 lambda a, nc: lax.dynamic_update_index_in_dim(a, nc, i, 0),
                 cache_full, new_c)
@@ -394,8 +381,7 @@ def ring_convert_cache(cfg: ModelConfig, cache, max_len: int, length: int):
     return {"stages": stages, "pos": cache["pos"]}
 
 
-def decode_step(params, cfg: ModelConfig, cache, tokens,
-                ctx: ShardCtx = NULL_CTX):
+def decode_step(params, cfg: ModelConfig, cache, tokens):
     """One decode iteration.  tokens: [B, 1] int32.  Returns
     (logits [B, V], new_cache, held): ``held`` [B] int32 counts each
     slot's (MoE layer, top-k pick) pairs that fell on an expert this chip
@@ -403,8 +389,6 @@ def decode_step(params, cfg: ModelConfig, cache, tokens,
     pos = cache["pos"]
     x = jnp.take(params["embed"], tokens, axis=0)
     B = x.shape[0]
-    bspec = ctx.batch_spec_entry(B)
-    x = ctx.constraint(x, bspec, None, None)
     held = (jnp.zeros((B,), jnp.int32)
             if any(b.ffn == "moe" for b in cfg.layer_list()) else None)
 
@@ -413,14 +397,12 @@ def decode_step(params, cfg: ModelConfig, cache, tokens,
         sp = params["stages"][si]
         sc = cache["stages"][si]
 
-        # the cache rides the scan CARRY and is updated in place at the
-        # layer index — XLA aliases while-loop carries, so decode keeps a
-        # single cache buffer instead of stacked xs/ys copies (which cost
-        # +2x cache per k/v at 32k contexts).  Attention and MLA layers
-        # store their new token as ``L.attn_decode`` says: under a mesh
-        # by a select into the layer slice inside the scan; with no mesh
-        # the scan only reads the cache and emits the token's rows, which
-        # ``L.write_token_rows`` writes after it.
+        # the cache rides the scan CARRY — XLA aliases while-loop carries,
+        # so decode keeps a single cache buffer instead of stacked xs/ys
+        # copies (which cost +2x cache per k/v at 32k contexts).  Attention
+        # and MLA layers only read it and emit the token's rows, which
+        # ``L.write_token_rows`` writes after the scan; an SSM layer's new
+        # state is written at the layer index inside it.
         def body(carry, inp, stage=stage):
             xx, cache_full, held = carry
             i, layer_p = inp
@@ -428,42 +410,33 @@ def decode_step(params, cfg: ModelConfig, cache, tokens,
                 lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
                 cache_full)
             new_c, rows = {}, {}
-
-            def store(key, c_, new):
-                if ctx.enabled:
-                    new_c[key] = {k: L.select_token(c_[k], n, pos)
-                                  for k, n in new.items()}
-                else:
-                    rows[key] = new
-
             for pi, blk in enumerate(stage.pattern):
                 p_ = layer_p[f"blk{pi}"]
                 if blk.mixer in ("full", "window"):
                     c_ = layer_c[f"blk{pi}"]
                     y, (kn, vn) = L.attn_decode(
                         p_["attn"], cfg, xx, c_["k"], c_["v"], pos,
-                        blk.window, ctx)
+                        blk.window)
                     xx = xx + y
-                    store(f"blk{pi}", c_, {"k": kn, "v": vn})
+                    rows[f"blk{pi}"] = {"k": kn, "v": vn}
                 elif blk.mixer == "mla":
                     c_ = layer_c[f"blk{pi}"]
                     y, (cn, krn) = L.mla_decode(
-                        p_["attn"], cfg, xx, c_["ckv"], c_["kr"], pos, ctx)
+                        p_["attn"], cfg, xx, c_["ckv"], c_["kr"], pos)
                     xx = xx + y
-                    store(f"blk{pi}", c_, {"ckv": cn, "kr": krn})
+                    rows[f"blk{pi}"] = {"ckv": cn, "kr": krn}
                 elif blk.mixer == "mamba":
                     c_ = layer_c[f"blk{pi}"]
                     y, (conv_s, ssm_s) = ssd.mamba_decode(
-                        p_["mixer"], cfg, xx, c_["conv"], c_["ssm"], ctx)
+                        p_["mixer"], cfg, xx, c_["conv"], c_["ssm"])
                     xx = xx + y
                     new_c[f"blk{pi}"] = {"conv": conv_s, "ssm": ssm_s}
                 if blk.ffn == "dense":
-                    xx = xx + L.ffn_forward(p_["ffn"], cfg, xx, ctx)
+                    xx = xx + L.ffn_forward(p_["ffn"], cfg, xx)
                 elif blk.ffn == "moe":
-                    y, _, h = L.moe_forward(p_["ffn"], cfg, xx, ctx)
+                    y, _, h = L.moe_forward(p_["ffn"], cfg, xx)
                     xx = xx + y
                     held = held + h[:, 0]
-            xx = ctx.constraint(xx, bspec, None, None)
             cache_full = {**cache_full, **{
                 key: jax.tree.map(
                     lambda a, n: lax.dynamic_update_index_in_dim(a, n, i, 0),

@@ -6,24 +6,16 @@ recurrence over per-chunk states carries long-range information.  The
 single-token decode path is the O(1) recurrent update used by the serving
 engine.  This module is also the pure-jnp oracle for ``kernels/ssd``.
 
-TPU sharding note: the input projection is stored as *separate* matrices
-(w_z / w_x / w_B / w_C / w_dt) rather than the fused in_proj of the CUDA
-reference.  The SSD recurrence is independent per head, so sharding the
-head dim (columns of w_z/w_x/w_dt, the conv channels, the state cache)
-over the model axis makes the whole mixer tensor-parallel with a single
-psum at the output projection; a fused in_proj would need an unsupported
-mixed column partitioning.  (Recorded in DESIGN.md §3.)
+The input projection is stored as *separate* matrices (w_z / w_x / w_B /
+w_C / w_dt) rather than the fused in_proj of the CUDA reference.
 """
 from __future__ import annotations
-
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from repro.configs.base import ModelConfig, SSMConfig
-from repro.distributed.context import NULL_CTX, ShardCtx
 from repro.models.layers import _normal, rms_norm
 
 
@@ -131,16 +123,14 @@ def ssd_chunked(x, dt, A, B_, C, chunk: int):
     return y.reshape(Bsz, L, H, Pd), h_final
 
 
-def mamba_forward(p, cfg: ModelConfig, x, ctx: ShardCtx = NULL_CTX,
-                  return_state: bool = False, use_kernel: bool = False):
+def mamba_forward(p, cfg: ModelConfig, x, return_state: bool = False,
+                  use_kernel: bool = False):
     """Full-sequence Mamba-2 block.  x: [B, L, d]."""
     s: SSMConfig = cfg.ssm
     d = cfg.d_model
     di = s.d_inner(d)
     nh = s.n_heads(d)
     B, L, _ = x.shape
-    bspec = ctx.batch_spec_entry(B)
-    mspec_h = ctx.model_axis_if_divides(nh)
 
     h = rms_norm(x, p["ln"], cfg.norm_eps)
     z = h @ p["w_z"]
@@ -148,8 +138,6 @@ def mamba_forward(p, cfg: ModelConfig, x, ctx: ShardCtx = NULL_CTX,
     B_raw = h @ p["w_B"]
     C_raw = h @ p["w_C"]
     dt_raw = h @ p["w_dt"]
-    z = ctx.constraint(z, bspec, None, ctx.model_axis_if_divides(di))
-    x_raw = ctx.constraint(x_raw, bspec, None, ctx.model_axis_if_divides(di))
 
     xs = _causal_conv(x_raw, p["conv_x"], p["conv_bx"])
     Bv = _causal_conv(B_raw, p["conv_B"], p["conv_bB"])
@@ -160,7 +148,6 @@ def mamba_forward(p, cfg: ModelConfig, x, ctx: ShardCtx = NULL_CTX,
     Cv = Cv.reshape(B, L, s.n_groups, s.d_state)
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])
     A = -jnp.exp(p["A_log"])
-    xs = ctx.constraint(xs, bspec, None, mspec_h, None)
 
     pad = (-L) % s.chunk
     if pad:
@@ -193,8 +180,7 @@ def mamba_forward(p, cfg: ModelConfig, x, ctx: ShardCtx = NULL_CTX,
     return out
 
 
-def mamba_decode(p, cfg: ModelConfig, x, conv_state, ssm_state,
-                 ctx: ShardCtx = NULL_CTX):
+def mamba_decode(p, cfg: ModelConfig, x, conv_state, ssm_state):
     """Single-token recurrent update.
 
     x: [B,1,d]; conv_state: {"x": [B,K-1,di], "B": [B,K-1,gn], "C": ...}

@@ -11,17 +11,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.distributed.context import NULL_CTX, ShardCtx
 
-
-def chunked_cross_entropy(hidden, w_head, labels, mask, chunk: int = 512,
-                          ctx: ShardCtx = NULL_CTX):
+def chunked_cross_entropy(hidden, w_head, labels, mask, chunk: int = 512):
     """hidden: [B,S,d]; w_head: [d,V]; labels/mask: [B,S].
 
     Returns (mean_nll, n_tokens)."""
     B, S, D = hidden.shape
-    # re-gather the sequence-parallel residual stream once before chunking
-    hidden = ctx.constraint(hidden, ctx.batch_spec_entry(B), None, None)
     chunk = min(chunk, S)
     pad = (-S) % chunk
     if pad:

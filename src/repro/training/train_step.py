@@ -12,7 +12,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.distributed.context import NULL_CTX, ShardCtx
 from repro.models.model import lm_head_weight, model_forward
 from repro.training.loss import chunked_cross_entropy
 from repro.training.optimizer import AdamWConfig, adamw_update
@@ -29,15 +28,14 @@ def cast_half(params, dtype=jnp.bfloat16):
     return jax.tree_util.tree_map_with_path(cast, params)
 
 
-def make_loss_fn(cfg: ModelConfig, ctx: ShardCtx = NULL_CTX,
-                 ce_chunk: int = 512, remat: bool = True):
+def make_loss_fn(cfg: ModelConfig, ce_chunk: int = 512, remat: bool = True):
     def loss_fn(params, tokens, labels, mask, prefix_embeds=None):
         p_half = cast_half(params)
         hidden, aux = model_forward(p_half, cfg, tokens, prefix_embeds,
-                                    ctx=ctx, remat=remat)
+                                    remat=remat)
         w = lm_head_weight(p_half, cfg)
         nll, ntok = chunked_cross_entropy(hidden, w, labels, mask,
-                                          chunk=ce_chunk, ctx=ctx)
+                                          chunk=ce_chunk)
         total = nll
         if cfg.moe is not None:
             total = total + cfg.moe.router_aux_weight * aux
@@ -46,9 +44,8 @@ def make_loss_fn(cfg: ModelConfig, ctx: ShardCtx = NULL_CTX,
 
 
 def make_train_step(cfg: ModelConfig, opt_cfg: AdamWConfig,
-                    ctx: ShardCtx = NULL_CTX, ce_chunk: int = 512,
-                    remat: bool = True):
-    loss_fn = make_loss_fn(cfg, ctx, ce_chunk, remat)
+                    ce_chunk: int = 512, remat: bool = True):
+    loss_fn = make_loss_fn(cfg, ce_chunk, remat)
 
     def train_step(params, opt_state, tokens, labels, mask,
                    prefix_embeds=None):
@@ -66,10 +63,9 @@ def make_train_step(cfg: ModelConfig, opt_cfg: AdamWConfig,
 
 
 def make_grad_accum_step(cfg: ModelConfig, opt_cfg: AdamWConfig,
-                         accum: int, ctx: ShardCtx = NULL_CTX,
-                         ce_chunk: int = 512):
+                         accum: int, ce_chunk: int = 512):
     """Micro-batched variant: batch leading dim is [accum, micro, ...]."""
-    loss_fn = make_loss_fn(cfg, ctx, ce_chunk)
+    loss_fn = make_loss_fn(cfg, ce_chunk)
 
     def step(params, opt_state, tokens, labels, mask, prefix_embeds=None):
         def micro(carry, inp):

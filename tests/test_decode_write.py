@@ -1,11 +1,10 @@
 """How a decode step stores its new token in the KV cache.
 
-With no mesh the step writes only the new token's K/V rows into the
-cache, once per row for all the layers of a stage; with a mesh it
-selects the token into each whole layer slice and stores the slice
-back.  The first test holds the row writes to the same numbers as a
-whole-slice masked select, written out here; the second checks which
-write each path compiles to.
+The step writes only the new token's K/V rows into the cache, once per
+row for all the layers of a stage.  The first test holds the row writes
+to the same numbers as a whole-slice masked select, written out here;
+the second checks that the step compiles to those row writes and to no
+whole-layer write.
 """
 import re
 
@@ -13,10 +12,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh
 
 from repro.configs import get_config, reduce_config
-from repro.distributed.context import NULL_CTX, ShardCtx
 from repro.models import decode_step, init_cache, init_params
 from repro.models import layers as L
 from repro.models.model import logits_fn
@@ -50,8 +47,7 @@ def _reference_step(params, cfg, cache, tokens):
                 ck, cv = c_["k"][r], c_["v"][r]
                 S = ck.shape[1]
                 h = L.rms_norm(x, p_["attn"]["ln"], cfg.norm_eps)
-                q, k, v = L._qkv(p_["attn"], cfg, h, pos[:, None], NULL_CTX,
-                                 decode=True)
+                q, k, v = L._qkv(p_["attn"], cfg, h, pos[:, None])
                 hit = (jnp.arange(S)[None, :]
                        == (pos % S)[:, None])[..., None, None]
                 ck = jnp.where(hit, k, ck)
@@ -104,7 +100,7 @@ def test_row_write_matches_masked_select(arch):
         {"full", "window"} if arch == "gemma3-12b" else {"full"})
 
 
-def _cache_writes(cfg, ctx):
+def _cache_writes(cfg):
     """(update shape, written shape) of every dynamic_update_slice in the
     lowered decode step."""
     B = len(POS0)
@@ -114,7 +110,7 @@ def _cache_writes(cfg, ctx):
     cache = jax.eval_shape(
         lambda: init_cache(cfg, B, MAX_LEN, dtype=jnp.float32))
     toks = jax.ShapeDtypeStruct((B, 1), jnp.int32)
-    text = jax.jit(lambda p, c, t: decode_step(p, cfg, c, t, ctx=ctx)) \
+    text = jax.jit(lambda p, c, t: decode_step(p, cfg, c, t)) \
         .lower(params, cache, toks).as_text()
     dus = re.findall(r"stablehlo\.dynamic_update_slice .*?: \(tensor<([^>]*)>"
                      r", tensor<([^>]*)>", text)
@@ -129,10 +125,7 @@ def test_decode_writes_rows_without_a_mesh_and_slices_with_one():
     layer = f"1x{B}x{S}x{KV}x{hd}xf32"
     rows = f"{R}x1x1x{KV}x{hd}xf32"      # one row of every layer
 
-    plain = [u for u, t in _cache_writes(cfg, NULL_CTX) if t == stack]
-    assert plain == [rows] * (2 * B), plain     # K and V, once per row
-
-    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
-    meshed = [u for u, t in _cache_writes(cfg, ShardCtx(mesh=mesh))
-              if t == stack]
-    assert set(meshed) == {layer}, meshed
+    writes = _cache_writes(cfg)
+    into_stack = [u for u, t in writes if t == stack]
+    assert into_stack == [rows] * (2 * B), into_stack   # K and V, per row
+    assert not [w for w in writes if w[0] == layer], writes

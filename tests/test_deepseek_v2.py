@@ -26,7 +26,6 @@ from chipbench import weights
 from chipbench.conftest import DEEPSEEK_SMALL as SMALL
 from chipbench.program import deepseek_v2 as program
 from chipbench.reference import deepseek_v2 as ref
-from repro.distributed.context import NULL_CTX
 from repro.engine.engine import EngineRequest, InferenceEngine
 from repro.models import decode_step, init_cache, init_params
 from repro.models import layers as L
@@ -174,9 +173,7 @@ def test_every_token_on_one_held_expert_drops_none(B, Lq):
     x = jnp.ones((B, Lq, cfg.d_model)) + 0.01 * jax.random.normal(
         jax.random.PRNGKey(6), (B, Lq, cfg.d_model))
     y, _, held = L.moe_forward(p, cfg, x)
-    T, K, E = B * Lq, cfg.moe.top_k, cfg.moe.num_experts
-    # the capacity dispatch would keep fewer of expert 1's tokens than come
-    assert L.moe_capacity(T, K, E, cfg.moe.capacity_factor) < T
+    T = B * Lq
     alone = jnp.stack([L.moe_forward(p, cfg, x.reshape(T, 1, 1, -1)[t])[0]
                        for t in range(T)]).reshape(y.shape)
     np.testing.assert_allclose(np.asarray(y), np.asarray(alone), atol=1e-5,
@@ -278,4 +275,4 @@ def test_held_routes_count_the_picks_on_held_experts():
     assert decode_step(init_params(dense, jax.random.PRNGKey(0),
                                    jnp.float32), dense,
                        init_cache(dense, 2, 8, jnp.float32),
-                       jnp.zeros((2, 1), jnp.int32), NULL_CTX)[2] is None
+                       jnp.zeros((2, 1), jnp.int32))[2] is None

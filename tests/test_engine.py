@@ -34,7 +34,6 @@ def test_engine_serves_batched_requests(engine):
         assert len(r.generated) >= 1
 
 
-@pytest.mark.slow
 def test_engine_matches_teacher_forcing():
     cfg = reduce_config(get_config("llama3.1-8b"))
     eng = InferenceEngine(cfg, max_batch=2, max_len=48, seed=3)
@@ -81,7 +80,6 @@ def test_drain_events_bounded_and_clearing():
     assert eng.drain_events() == []          # drained means drained
 
 
-@pytest.mark.slow
 def test_chunked_prefill_matches_oneshot():
     """Greedy continuations must be token-identical whether the prompt
     was prefetched in one shot or staged through the chunked path

@@ -123,11 +123,11 @@ def test_engine_programs_carry_their_names():
     staged = eng._staging_cache()
     lowered = {
         "jit_engine_decode": DECODE.lower(eng.params, eng.cache, toks,
-                                          cfg=CFG, ctx=eng.ctx),
+                                          cfg=CFG),
         "jit_engine_prefill": PREFILL.lower(eng.params, prompt, cfg=CFG,
-                                            max_len=48, ctx=eng.ctx),
+                                            max_len=48),
         "jit_engine_prefill_chunk": PREFILL_CHUNK.lower(
-            eng.params, staged, prompt, np.asarray([8], np.int32), cfg=CFG,
-            ctx=eng.ctx)}
+            eng.params, staged, prompt, np.asarray([8], np.int32),
+            cfg=CFG)}
     for name, low in lowered.items():
         assert f"module @{name} " in low.as_text()

@@ -1,7 +1,7 @@
 """Every repro.* module must import on the installed JAX (an import
 error there fails the whole suite's collection).  The sweep runs in a
-subprocess because ``repro.launch.dryrun`` sets XLA_FLAGS at import time
-and must not poison jax device config for the rest of this process.
+fresh interpreter, so that each module is shown to import without what
+other tests of this process imported first.
 """
 import os
 import subprocess

@@ -21,7 +21,6 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.configs.base import Stage, uniform_stage
-from repro.distributed.context import NULL_CTX
 from repro.engine.engine import DECODE, engine_prefill
 from repro.kernels.flash_attention.flash_attention import \
     flash_attention_pallas
@@ -137,7 +136,7 @@ def test_minicpm_decode_compiles_at_full_width(one_chip, minicpm_2_layers):
     cfg = minicpm_2_layers
     params, cache = _model_shapes(cfg, one_chip, CHIP_BATCH, CHIP_MAX_LEN)
     toks = _spec(one_chip, (CHIP_BATCH, 1), jnp.int32)
-    DECODE.lower(params, cache, toks, cfg=cfg, ctx=NULL_CTX).compile()
+    DECODE.lower(params, cache, toks, cfg=cfg).compile()
 
 
 def test_minicpm_prefill_compiles_at_full_width(one_chip, minicpm_2_layers):
@@ -154,8 +153,7 @@ def test_smoke_decode_fits_one_chip(one_chip):
     cfg = chip_config("minicpm-2b")
     params, cache = _model_shapes(cfg, one_chip, CHIP_BATCH, CHIP_MAX_LEN)
     toks = _spec(one_chip, (CHIP_BATCH, 1), jnp.int32)
-    compiled = DECODE.lower(params, cache, toks, cfg=cfg,
-                            ctx=NULL_CTX).compile()
+    compiled = DECODE.lower(params, cache, toks, cfg=cfg).compile()
     assert compiled.memory_analysis().alias_size_in_bytes > 0   # donated
     assert _total_bytes(compiled) < V5E_USABLE_BYTES
 
@@ -166,8 +164,7 @@ def test_minicpm_decode_keeps_one_cache_buffer(one_chip, minicpm_2_layers):
     cfg = minicpm_2_layers
     params, cache = _model_shapes(cfg, one_chip, CHIP_BATCH, CHIP_MAX_LEN)
     toks = _spec(one_chip, (CHIP_BATCH, 1), jnp.int32)
-    compiled = DECODE.lower(params, cache, toks, cfg=cfg,
-                            ctx=NULL_CTX).compile()
+    compiled = DECODE.lower(params, cache, toks, cfg=cfg).compile()
     assert compiled.memory_analysis().alias_size_in_bytes > 0   # donated
     stack = ",".join(map(str, cache["stages"][0]["blk0"]["k"].shape))
     copies = re.findall(rf"= f32\[{stack}\]\{{[^}}]*\}} copy\(",
@@ -194,7 +191,7 @@ def test_deepseek_decode_compiles_at_full_width(one_chip, deepseek_2_layers):
     params, cache = _model_shapes(cfg, one_chip, DEEPSEEK_BATCH,
                                   DEEPSEEK_MAX_LEN)
     toks = _spec(one_chip, (DEEPSEEK_BATCH, 1), jnp.int32)
-    DECODE.lower(params, cache, toks, cfg=cfg, ctx=NULL_CTX).compile()
+    DECODE.lower(params, cache, toks, cfg=cfg).compile()
 
 
 def test_deepseek_prefill_compiles_at_full_width(one_chip, deepseek_2_layers):
@@ -231,7 +228,7 @@ def test_deepseek_cell_decode_fits_one_chip_and_copies_no_latent_cache(
     B, S = spec["serve"]["max_batch"], spec["serve"]["max_len"]
     params, cache = _model_shapes(cfg, one_chip, B, S)
     compiled = DECODE.lower(params, cache, _spec(one_chip, (B, 1), jnp.int32),
-                            cfg=cfg, ctx=NULL_CTX).compile()
+                            cfg=cfg).compile()
     assert compiled.memory_analysis().alias_size_in_bytes > 0   # donated
     assert _total_bytes(compiled) < V5E_USABLE_BYTES
     text = compiled.as_text()
@@ -277,7 +274,7 @@ def _compiled_text(cfg, sharding, program, batch, max_len, prompt, served):
     if program == "decode":
         fn = DECODE.lower(params, cache,
                           _spec(sharding, (batch, 1), jnp.int32),
-                          cfg=cfg, ctx=NULL_CTX)
+                          cfg=cfg)
     else:
         fn = jax.jit(lambda p, t: engine_prefill(
             p, t, cfg=cfg, max_len=max_len)).lower(

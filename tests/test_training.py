@@ -6,7 +6,6 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config, reduce_config
-from repro.distributed.context import NULL_CTX
 from repro.models import init_params
 from repro.training.checkpoint import (latest_step, restore_checkpoint,
                                        save_checkpoint)
@@ -76,8 +75,8 @@ def test_grad_accum_matches_full_batch():
                                 cfg.vocab_size)
     mask = jnp.ones((B, S), jnp.float32)
     ocfg = AdamWConfig(lr=1e-3, total_steps=10, grad_clip=1e9)
-    full = make_train_step(cfg, ocfg, NULL_CTX, ce_chunk=8)
-    accum = make_grad_accum_step(cfg, ocfg, A, NULL_CTX, ce_chunk=8)
+    full = make_train_step(cfg, ocfg, ce_chunk=8)
+    accum = make_grad_accum_step(cfg, ocfg, A, ce_chunk=8)
     p1, _, m1 = jax.jit(full)(params, opt, toks, labels, mask)
     p2, _, m2 = jax.jit(accum)(params, opt,
                                toks.reshape(A, B // A, S),
